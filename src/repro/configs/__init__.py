@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
+from typing import Optional
 
 from repro.models.config import ModelConfig
 
@@ -36,8 +37,14 @@ def _module(name: str):
     return importlib.import_module(f"repro.configs.{key}")
 
 
-def get(name: str) -> ModelConfig:
-    return _module(name).CONFIG
+def get(name: str, n_layers: Optional[int] = None) -> ModelConfig:
+    """The published config.  ``n_layers`` cuts its depth: the config is
+    rebuilt with that many layers and the group count recomputed, every
+    width untouched."""
+    cfg = _module(name).CONFIG
+    if n_layers is None:
+        return cfg
+    return dataclasses.replace(cfg, n_layers=int(n_layers), n_groups=0)
 
 
 def get_tiny(name: str) -> ModelConfig:

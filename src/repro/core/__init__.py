@@ -6,6 +6,8 @@ search-space codecs, and the cost backends used across the framework.
 from .autotuning import Autotuning
 from .costs import (
     TPU_V5E,
+    PEAKS,
+    peaks_for,
     CachePartition,
     ExecutableCache,
     HardwareSpec,
@@ -94,6 +96,8 @@ __all__ = [
     "HardwareSpec",
     "RooflineTerms",
     "TPU_V5E",
+    "PEAKS",
+    "peaks_for",
     "collective_bytes",
     "hlo_flops_bytes",
     "roofline_terms",

@@ -36,6 +36,8 @@ from repro.obs.trace import tracer as _tracer
 __all__ = [
     "HardwareSpec",
     "TPU_V5E",
+    "PEAKS",
+    "peaks_for",
     "RuntimeCost",
     "ExecutableCache",
     "CachePartition",
@@ -61,9 +63,19 @@ class HardwareSpec:
         return self.name
 
 
-# Brief-mandated constants: 197 TFLOP/s bf16; 819 GB/s HBM; ~50 GB/s/link ICI;
-# 16 GB HBM per chip.
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at
+# 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect (4 links of 50 GB/s).
 TPU_V5E = HardwareSpec("tpu-v5e", 197e12, 819e9, 50e9, 16e9)
+
+#: published peaks per chip, keyed by ``jax.Device.device_kind``
+PEAKS = {"TPU v5 lite": TPU_V5E}
+
+
+def peaks_for(device_kind: str) -> Optional[HardwareSpec]:
+    """The published peaks of ``device_kind``, or ``None`` for a kind not in
+    :data:`PEAKS` — such a device gets no roofline bound, never another
+    chip's."""
+    return PEAKS.get(device_kind)
 
 
 class RuntimeCost:
@@ -94,12 +106,8 @@ class RuntimeCost:
         self.last_times: list = []  # raw measured reps of the latest call
 
     def __call__(self, fn: Callable, *args, **kwargs) -> float:
-        try:
-            import jax
+        from jax import block_until_ready as block
 
-            block = jax.block_until_ready
-        except Exception:  # pragma: no cover - jax always present here
-            block = lambda x: x
         self.last_times = []
         try:
             for _ in range(self.warmup):
@@ -605,7 +613,7 @@ class RooflineTerms:
 def roofline_terms(
     compiled,
     chips: int,
-    hw: HardwareSpec = TPU_V5E,
+    hw: HardwareSpec,
     hlo_text: Optional[str] = None,
     links_per_chip: int = 4,
 ) -> RooflineTerms:

@@ -288,8 +288,22 @@ def sandboxed_probe(
 
     Uses ``fork`` (POSIX) so arbitrary closures need no pickling; on
     platforms without ``fork`` the probe is skipped (returns True) — the
-    sandbox is an opt-in belt-and-braces layer, never a hard dependency."""
+    sandbox is an opt-in belt-and-braces layer, never a hard dependency.
+
+    Only the CPU backend can be probed: an accelerator belongs to the one
+    process that opened it, so a forked child would fail or hang on it.  On
+    any other backend the probe raises :class:`RuntimeError` instead."""
     import multiprocessing as mp
+
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"sandboxed probe of {label or 'candidate'} refused: a forked child "
+            f"cannot use the {backend} device this process holds; run without "
+            "FaultPolicy(sandbox_first_touch=True) here"
+        )
 
     try:
         ctx = mp.get_context("fork")

@@ -106,12 +106,8 @@ def objective_value(times: Sequence[float], objective: str = "median") -> float:
 def time_rep(fn: Callable, *args, **kwargs) -> float:
     """One timed repetition of ``fn(*args)``; blocks on the result so
     asynchronous dispatch is included (the unit the ladder escalates in)."""
-    try:
-        import jax
+    from jax import block_until_ready as block
 
-        block = jax.block_until_ready
-    except Exception:  # pragma: no cover - jax always present here
-        block = lambda x: x
     t0 = time.perf_counter()
     block(fn(*args, **kwargs))
     return time.perf_counter() - t0

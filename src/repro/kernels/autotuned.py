@@ -52,7 +52,7 @@ from repro.core.measure import ENV_TUNE_MEASURE  # noqa: F401 - public re-export
 from repro.obs import events as _events
 from repro.obs.log import get_logger
 from repro.obs.trace import tracer as _tracer
-from repro.tuning import TuningDB, default_db, make_key
+from repro.tuning import TuningDB, default_db, default_device, make_key
 
 from . import ops
 
@@ -121,6 +121,21 @@ def _log_dim(name: str, n: int, lo: int, cap: int) -> LogIntDim:
     return LogIntDim(name, lo, hi)
 
 
+def _lane_tile(n: int, cap: int) -> int:
+    """Largest tile the chip accepts along a block's last dimension: a
+    multiple of 128 that divides ``n`` (a power of two up to ``cap``), or
+    ``n`` itself when 128 does not divide it."""
+    return n if n % 128 else min(cap, _pow2_floor(n))
+
+
+def _lane_dim(name: str, n: int, cap: int) -> LogIntDim:
+    """Tile dim along a block's last dimension, holding only tiles the TPU's
+    (8, 128) block tiling accepts: 128 up to :func:`_lane_tile`, or just ``n``."""
+    n = int(n)
+    hi = _lane_tile(n, cap)
+    return LogIntDim(name, hi if n % 128 else 128, hi)
+
+
 # --------------------------------------------------------- registered kernels
 register(
     KernelSpec(
@@ -129,14 +144,15 @@ register(
         space=lambda a, b: SearchSpace(
             [
                 _log_dim("bm", a.shape[0], 32, 256),
-                _log_dim("bn", b.shape[1], 32, 256),
-                _log_dim("bk", a.shape[1], 32, 256),
+                # bn, bk are the last dims of the (bk, bn) / (bm, bk) blocks
+                _lane_dim("bn", b.shape[1], 256),
+                _lane_dim("bk", a.shape[1], 256),
             ]
         ),
         defaults=lambda a, b: {
             "bm": min(256, _pow2_floor(a.shape[0])),
-            "bn": min(256, _pow2_floor(b.shape[1])),
-            "bk": min(256, _pow2_floor(a.shape[1])),
+            "bn": _lane_tile(b.shape[1], 256),
+            "bk": _lane_tile(a.shape[1], 256),
         },
     )
 )
@@ -164,10 +180,9 @@ register(
         name="decode_attention",
         fn=ops.decode_attention,
         # q: (B,H,hd); k/v: (B,Kh,S,hd); valid: (B,S)
-        space=lambda q, k, v, valid: SearchSpace(
-            [_log_dim("block_kv", k.shape[2], 64, 1024)]
-        ),
-        defaults=lambda q, k, v, valid: {"block_kv": min(512, _pow2_floor(k.shape[2]))},
+        # block_kv is the last dim of the mask's (1, 1, block_kv) block
+        space=lambda q, k, v, valid: SearchSpace([_lane_dim("block_kv", k.shape[2], 1024)]),
+        defaults=lambda q, k, v, valid: {"block_kv": _lane_tile(k.shape[2], 512)},
     )
 )
 
@@ -277,19 +292,20 @@ def _resolve_jobs(jobs: Optional[int]) -> int:
 
 
 def _roofline_bound(ex) -> Optional[float]:
-    """Analytic lower bound (seconds) of a compiled executable, or ``None``
-    when cost analysis is unavailable.  Conservative by construction: the
-    bound assumes peak accelerator hardware (the default
-    :data:`~repro.core.costs.TPU_V5E` spec), so it can only *under*-estimate
-    the real wall time — a candidate is pruned only when even its ideal
-    execution loses to the incumbent's measured cost.  On hosts far slower
-    than the spec (CPU interpret mode) the bound sits orders of magnitude
-    below any measurement and the prefilter simply never fires; pass an
-    explicit ``bound_fn`` to :func:`tune_call` for host-calibrated bounds."""
-    from repro.core import roofline_terms
+    """Analytic lower bound (seconds) of a compiled executable on the current
+    device, or ``None`` when the device's kind has no published peaks
+    (:func:`~repro.core.costs.peaks_for`) or cost analysis is unavailable.
+    Conservative by construction: the bound assumes the device's peaks, so it
+    can only *under*-estimate the real wall time — a candidate is pruned only
+    when even its ideal execution loses to the incumbent's measured cost.
+    Pass an explicit ``bound_fn`` to :func:`tune_call` for other bounds."""
+    from repro.core import peaks_for, roofline_terms
 
+    hw = peaks_for(default_device()[1])
+    if hw is None:
+        return None
     try:
-        b = float(roofline_terms(ex, chips=1).bound_s)
+        b = float(roofline_terms(ex, chips=1, hw=hw).bound_s)
     except Exception:
         return None
     return b if b > 0.0 else None
@@ -839,10 +855,7 @@ def routed(
         # shape-bucket's space into this exact shape's space
         spec = get_spec(name)
         out = spec.fn(*args, **kwargs, **decision.point, interpret=interpret)
-    try:
-        out = jax.block_until_ready(out)
-    except Exception:
-        pass
+    out = jax.block_until_ready(out)
     r.observe(decision, _time.perf_counter() - t0)
     return out
 

@@ -17,7 +17,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 
 __all__ = ["decode_attention_fwd"]
 
@@ -36,7 +35,7 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_scr, l_scr, acc_scr, *, sca
     q = q_ref[0, 0].astype(jnp.float32) * scale  # (g, hd) — the GQA group
     k = k_ref[0, 0].astype(jnp.float32)  # (bkv, hd)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    s = jnp.where((valid_ref[0] > 0)[None, :], s, NEG_INF)  # (g, bkv)
+    s = jnp.where(valid_ref[0] > 0, s, NEG_INF)  # (1, bkv) mask over (g, bkv)
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
@@ -76,7 +75,9 @@ def decode_attention_fwd(q, k, v, valid, *, block_kv: int = 512, interpret: bool
             pl.BlockSpec((1, 1, g, hd), lambda b, h, ikv: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, block_kv, hd), lambda b, h, ikv: (b, h, ikv, 0)),
             pl.BlockSpec((1, 1, block_kv, hd), lambda b, h, ikv: (b, h, ikv, 0)),
-            pl.BlockSpec((1, block_kv), lambda b, h, ikv: (b, ikv)),
+            # (1, 1, block_kv) of the (B, 1, S) mask: a (1, block_kv) block of
+            # a (B, S) array breaks the TPU's (8, 128) tiling rule for B > 1
+            pl.BlockSpec((1, 1, block_kv), lambda b, h, ikv: (b, 0, ikv)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, hd), lambda b, h, ikv: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Kh, g, hd), q.dtype),
@@ -85,9 +86,9 @@ def decode_attention_fwd(q, k, v, valid, *, block_kv: int = 512, interpret: bool
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(qg, k, v, valid)
+    )(qg, k, v, valid.reshape(B, 1, S))
     return out.reshape(B, H, hd)

@@ -6,8 +6,9 @@ oracles the kernels are validated against).  Writing fused backward kernels
 is listed as future work in DESIGN.md; the custom-vjp split keeps training
 correct on day one while the forward hot path uses the tuned kernels.
 
-All wrappers accept ``interpret=True`` so the kernel *bodies* execute on CPU
-for validation (this container has no TPU).
+All wrappers accept ``interpret=True`` so the kernel *bodies* execute on the
+CPU backend for validation; :func:`interpret_mode` is that choice made from
+the platform.
 """
 from __future__ import annotations
 
@@ -22,7 +23,13 @@ from .flash_attention import flash_attention_fwd
 from .linear_scan import lru_scan_chunked, rwkv_scan_chunked
 from .matmul import matmul_tiled
 
-__all__ = ["flash_attention", "decode_attention", "rwkv_scan", "lru_scan", "matmul"]
+__all__ = ["flash_attention", "decode_attention", "rwkv_scan", "lru_scan", "matmul", "interpret_mode"]
+
+
+def interpret_mode() -> bool:
+    """Whether kernels run in Pallas interpret mode: only on the CPU backend,
+    which cannot run them compiled.  Every other platform runs them compiled."""
+    return jax.default_backend() == "cpu"
 
 
 # ---------------------------------------------------------- flash attention

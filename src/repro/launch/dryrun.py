@@ -306,13 +306,7 @@ def run_cell(
             "argument_bytes": mem.argument_size_in_bytes,
             "output_bytes": mem.output_size_in_bytes,
             "temp_bytes": mem.temp_size_in_bytes,
-            # older jaxlib has no peak stat; args+outputs+temps is the upper
-            # bound XLA itself reports for those versions
-            "peak_bytes": getattr(
-                mem,
-                "peak_memory_in_bytes",
-                mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes,
-            ),
+            "peak_bytes": mem.peak_memory_in_bytes,
             "alias_bytes": mem.alias_size_in_bytes,
         },
         "cost_reported": step_cost.as_dict(),

@@ -3,11 +3,7 @@ module never touches jax device state)."""
 from __future__ import annotations
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes have no axis types yet
-    AxisType = None
+from jax.sharding import AxisType
 
 from repro.parallel.api import ShardingRules
 
@@ -15,9 +11,7 @@ __all__ = ["make_production_mesh", "make_mesh", "default_rules"]
 
 
 def _make_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -3,6 +3,8 @@ PATSMA-tuned decode fusion depth.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2_7b --tiny \
         --batch 8 --prompt-len 32 --gen 64 --db tuned/serve.json
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen2_7b --layers 6 \
+        --batch 8 --prompt-len 128 --gen 32     # published widths, 6 of 28 layers
 
 Decode is routed through a :class:`repro.runtime.ContextRouter`: each
 (arch × batch size) is its own tuning context keyed by ``TuningKey``
@@ -54,10 +56,29 @@ def replay_decode_k(db, key, *, gen: int, default: int = 1) -> int:
     return max(1, min(k, gen))
 
 
-def main():
+def decode_chunk(model, k: int):
+    """The jitted decode of ``k`` greedy tokens in one dispatched scan:
+    ``(params, token, states, pos) -> (token, states, pos, tokens)``."""
+    @jax.jit
+    def run(params, token, states, pos):
+        def body(carry, _):
+            token, states, pos = carry
+            lg, states = model.decode_step(params, token, states, pos)
+            nxt = jnp.argmax(lg, -1).astype(jnp.int32)[:, None]
+            return (nxt, states, pos + 1), nxt
+        (token, states, pos), toks = jax.lax.scan(
+            body, (token, states, pos), None, length=k)
+        return token, states, pos, toks
+    return run
+
+
+def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", type=str, default="qwen2_7b")
     ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the published config to this many layers "
+                         "(widths untouched) so it fits the device")
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=64)
@@ -77,9 +98,19 @@ def main():
                     help="write observability artifacts (events.jsonl, "
                          "trace.json, metrics.json) into this directory "
                          "(default: the REPRO_OBS env var, else off)")
+    return ap
+
+
+def main():
+    ap = parser()
     args = ap.parse_args()
+    if args.tiny and args.layers is not None:
+        ap.error("--layers cuts the published config; it does not apply with --tiny")
 
     from repro import obs
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     if args.obs_dir:
         obs.configure(args.obs_dir)
@@ -92,9 +123,17 @@ def main():
         obs.shutdown()
 
 
-def _serve(args):
-
-    cfg = configs.get_tiny(args.arch) if args.tiny else configs.get(args.arch)
+def _serve(args) -> dict:
+    """Prefill then decode ``args.gen`` tokens.  Returns the served
+    ``model``/``params``, the prompt ``batch`` and the decode ``router``
+    (``None`` with ``--no-tune``) for callers that check the run."""
+    if args.tiny:
+        cfg = configs.get_tiny(args.arch)
+    else:
+        cfg = configs.get(args.arch, n_layers=args.layers)
+        if args.layers is not None:
+            print(f"depth cut: {cfg.n_layers} of {configs.get(args.arch).n_layers} "
+                  "layers; widths as published")
     model = Model(cfg, ExecConfig(rec_chunk=4))
     params = model.init(jax.random.PRNGKey(0))
     B, P = args.batch, args.prompt_len
@@ -116,17 +155,7 @@ def _serve(args):
     print(f"prefill {B}x{P}: {(time.perf_counter()-t0)*1e3:.0f} ms")
 
     def make_multi(k):
-        @jax.jit
-        def run(params, token, states, pos):
-            def body(carry, _):
-                token, states, pos = carry
-                lg, states = model.decode_step(params, token, states, pos)
-                nxt = jnp.argmax(lg, -1).astype(jnp.int32)[:, None]
-                return (nxt, states, pos + 1), nxt
-            (token, states, pos), toks = jax.lax.scan(
-                body, (token, states, pos), None, length=k)
-            return token, states, pos, toks
-        return run
+        return decode_chunk(model, k)
 
     # only depths that fit the stream are candidates: a k > --gen chunk can
     # never run whole, so it could never be measured and the search would
@@ -143,6 +172,8 @@ def _serve(args):
             stats = merge_dbs(db, [TuningDB(p, autosave=False) for p in args.db[1:]])
             print(f"merged {len(args.db) - 1} shard DB(s) into {args.db[0]}: {stats}")
     extra = {"arch": args.arch, "tiny": args.tiny, "batch": args.batch}
+    if args.layers is not None:
+        extra["layers"] = args.layers
     key = make_key("serve/decode_k", space=space, extra=extra) if db else None
     pos = jnp.int32(P)
     tail_fns = {}  # final-chunk sizes (k > remaining): compiled on demand
@@ -164,7 +195,7 @@ def _serve(args):
         wall = time.perf_counter() - t0
         print(f"decode: {emitted} tok/seq x {B} in {wall*1e3:.0f} ms "
               f"({B*emitted/wall:.0f} tok/s); static k={k_static}")
-        return
+        return {"model": model, "params": params, "batch": batch, "router": None}
 
     # adaptive serving: per-(arch, batch) decode-k context with background
     # candidate compiles and mid-stream drift re-tuning
@@ -235,6 +266,7 @@ def _serve(args):
           f"{rs['drift_resets']} drift re-tunes, "
           f"{rs['cache']['misses']} compiles ({rs['inband_builds']} in-band), "
           f"{rs['cache']['recompiles']} recompiles")
+    return {"model": model, "params": params, "batch": batch, "router": router}
 
 
 if __name__ == "__main__":

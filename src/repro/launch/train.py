@@ -47,6 +47,9 @@ def main():
         jax.distributed.initialize()
 
     from repro import obs
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     if args.obs_dir:
         obs.configure(args.obs_dir)

@@ -46,6 +46,28 @@ def init_attn_cache(cfg: ModelConfig, batch: int, max_len: int, *, window: int =
     }
 
 
+def _ring_write(cache: dict, kc, vc, q_pos) -> dict:
+    """Store the n entries at consecutive absolute positions ``q_pos`` in the
+    ring of W slots, entry i at slot (q_pos[0] + i) mod W.  n == W rewrites
+    the whole ring (a rotation); n < W is one contiguous write, which never
+    wraps here: a prefill starts at position 0 and a decode step writes one
+    slot.  Slice updates rather than a scatter: the TPU compiler aborts on
+    the scatter form of this write."""
+    W = cache["k"].shape[2]
+    s0 = jnp.mod(q_pos[0], W).astype(jnp.int32)
+    pos = q_pos.astype(jnp.int32)
+    if kc.shape[2] == W:
+        return {"k": jnp.roll(kc.astype(cache["k"].dtype), s0, axis=2),
+                "v": jnp.roll(vc.astype(cache["v"].dtype), s0, axis=2),
+                "pos": jnp.roll(pos, s0)}
+    z = jnp.int32(0)
+    return {
+        "k": jax.lax.dynamic_update_slice(cache["k"], kc.astype(cache["k"].dtype), (z, z, s0, z)),
+        "v": jax.lax.dynamic_update_slice(cache["v"], vc.astype(cache["v"].dtype), (z, z, s0, z)),
+        "pos": jax.lax.dynamic_update_slice(cache["pos"], pos, (s0,)),
+    }
+
+
 def _mask(q_pos, kv_pos, *, causal: bool, window: int):
     """(Sq, Skv) bool validity mask from absolute positions."""
     valid = kv_pos[None, :] >= 0
@@ -184,21 +206,11 @@ def attn_apply(
             out = _sdpa(q, kc, vc, mask)
         if Sq > W:
             kc, vc, q_pos = kc[:, :, Sq - W :], vc[:, :, Sq - W :], q_pos[Sq - W :]
-        slots = jnp.mod(q_pos, W)
-        new_cache = {
-            "k": cache["k"].at[:, :, slots].set(kc),
-            "v": cache["v"].at[:, :, slots].set(vc),
-            "pos": cache["pos"].at[slots].set(q_pos.astype(jnp.int32)),
-        }
+        new_cache = _ring_write(cache, kc, vc, q_pos)
         return linear_apply(p["wo"], out.reshape(B, Sq, H * hd)), new_cache
 
     # decode: append one step into the ring, attend over the cache
-    slots = jnp.mod(q_pos, W)
-    new_cache = {
-        "k": cache["k"].at[:, :, slots].set(kc),
-        "v": cache["v"].at[:, :, slots].set(vc),
-        "pos": cache["pos"].at[slots].set(q_pos.astype(jnp.int32)),
-    }
+    new_cache = _ring_write(cache, kc, vc, q_pos)
     if exec_cfg.attn_impl == "flashcost":
         out = _sdpa_flashcost(q, new_cache["k"], new_cache["v"])
     else:

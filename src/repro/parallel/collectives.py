@@ -28,8 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ._compat import shard_map
-
 __all__ = [
     "int8_psum",
     "topk_psum",
@@ -153,7 +151,7 @@ def make_compressed_dp_step(
 
     def step(params, opt_state, err, batch):
         batch_specs = jax.tree.map(lambda _: P(axis), batch)
-        fn = shard_map(
+        fn = jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(rep(params), rep(opt_state), rep(err), batch_specs),

@@ -10,10 +10,8 @@ devices, each slot carrying its own partition of a shared
 two devices is two distinct executables — partitioned keys keep them from
 colliding while the LRU budget and stats stay shared).
 
-On a CPU-only host (or when jax is unavailable) the slots have
-``device=None`` and measurement falls back to plain host threads — the
-fleet degrades to concurrency without device parallelism, never to an
-error.
+On a CPU-only host the slots share its CPU device(s).  A jax backend that
+fails to start raises here rather than handing out device-less slots.
 """
 from __future__ import annotations
 
@@ -26,16 +24,14 @@ __all__ = ["DeviceSlot", "local_device_pool"]
 @dataclasses.dataclass
 class DeviceSlot:
     """One fleet worker's execution context: its index, the jax device it
-    pins measurements to (None → default placement), and its namespaced
-    executable cache."""
+    pins measurements to, and its namespaced executable cache."""
 
     index: int
-    device: Optional[Any]
+    device: Any
     cache: Optional[Any] = None
 
     def __str__(self) -> str:
-        dev = "host" if self.device is None else str(self.device)
-        return f"slot{self.index}[{dev}]"
+        return f"slot{self.index}[{self.device}]"
 
 
 def local_device_pool(num_slots: int, *, cache=None) -> List[DeviceSlot]:
@@ -46,18 +42,12 @@ def local_device_pool(num_slots: int, *, cache=None) -> List[DeviceSlot]:
     chip also share its compiled executables."""
     if num_slots < 1:
         raise ValueError(f"num_slots must be >= 1, got {num_slots}")
-    try:
-        import jax
+    import jax
 
-        devices = list(jax.local_devices())
-    except Exception:
-        devices = []
+    devices = list(jax.local_devices())
     slots = []
     for i in range(num_slots):
-        device = devices[i % len(devices)] if devices else None
-        part = None
-        if cache is not None:
-            tag = f"dev{i % len(devices)}" if devices else "host"
-            part = cache.partition(tag)
-        slots.append(DeviceSlot(index=i, device=device, cache=part))
+        d = i % len(devices)
+        part = cache.partition(f"dev{d}") if cache is not None else None
+        slots.append(DeviceSlot(index=i, device=devices[d], cache=part))
     return slots

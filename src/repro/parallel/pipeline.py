@@ -65,10 +65,8 @@ def gpipe_apply(
         ys = jnp.where(sid == S - 1, ys, jnp.zeros_like(ys))
         return jax.lax.psum(ys, axis)
 
-    from ._compat import shard_map
-
     specs_params = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(specs_params, P()),

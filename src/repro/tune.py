@@ -131,8 +131,6 @@ def _db_list(argv) -> int:
     )
     ap.add_argument("--smoke", action="store_true",
                     help="with --grid: the smoke grid (CI lane)")
-    ap.add_argument("--no-interpret", action="store_true",
-                    help="with --grid: fingerprint compiled (non-interpret) contexts")
     ap.add_argument(
         "--shard", type=str, default=None, metavar="I/N",
         help="restrict to the contexts of one fleet shard",
@@ -149,13 +147,14 @@ def _db_list(argv) -> int:
         shard = parse_shard(args.shard)
 
     if args.grid:
+        from repro.kernels.ops import interpret_mode
         from repro.tuning.pretune import _cases, _list_grid, _shard_filter
 
         cases = _cases(args.smoke, abstract=True)
         if shard is not None:
             cases = _shard_filter(cases, args.smoke, None, None, shard,
-                                  interpret=not args.no_interpret)
-        return _list_grid(cases, db, interpret=not args.no_interpret)
+                                  interpret=interpret_mode())
+        return _list_grid(cases, db, interpret=interpret_mode())
 
     records = db.records()
     if shard is not None:
@@ -301,6 +300,9 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     try:
         code = main()
     except BrokenPipeError:  # e.g. `... db list | head` closing the pipe

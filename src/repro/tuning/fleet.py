@@ -475,20 +475,20 @@ def device_bound_measure(measure: Callable, slots: Sequence) -> Callable:
     """Pin each member's evaluations to its device slot: member ``i`` runs
     ``measure`` under ``jax.default_device(slots[i % len(slots)].device)``,
     so a multi-device host measures the whole field concurrently — one
-    member per chip — instead of queueing on device 0.  Slots with no device
-    (CPU-only hosts) pass through unchanged."""
+    member per chip — instead of queueing on device 0.
+
+    This moves only the *default* placement: a computation follows arrays
+    already committed to a device, so inputs made once on device 0 would
+    pull every member back onto device 0.  ``measure`` must build each
+    member's inputs on its slot's device (``jax.device_put(x, slot.device)``)."""
     slots = list(slots)
     if not slots:
         return measure
 
     def wrapped(i: int, points):
-        slot = slots[i % len(slots)]
-        device = getattr(slot, "device", None)
-        if device is None:
-            return measure(i, points)
         import jax
 
-        with jax.default_device(device):
+        with jax.default_device(slots[i % len(slots)].device):
             return measure(i, points)
 
     return wrapped

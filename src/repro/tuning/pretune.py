@@ -37,11 +37,14 @@ never recompile.  ``--measure adaptive`` (the default) races each round's
 candidates — dominated ones are culled after a single repetition and
 roofline-hopeless ones skip measurement — while ``--measure fixed`` keeps
 the classic ``RuntimeCost`` fixed-repeat loop for trajectory-pinned
-reproduction; the run summary reports repetitions spent, culls, and prunes.  The committed ``tuned/cpu.json`` snapshot is what the test
-suite and CI replay: the suite's kernel dispatches become exact fingerprint
-hits, so they skip straight to the stored best with zero re-measurement.  On
-a TPU host the same command (without ``--smoke``) produces the production
-snapshot for that device kind.
+reproduction; the run summary reports repetitions spent, culls, and prunes.
+A case that commits no record (every candidate failed) makes the sweep exit
+1.  Kernels run in Pallas interpret mode on the CPU backend and compiled on
+every other platform.  The committed ``tuned/cpu.json`` snapshot is what the
+test suite and CI replay: the suite's kernel dispatches become exact
+fingerprint hits, so they skip straight to the stored best with zero
+re-measurement.  On a TPU host the same command (without ``--smoke``)
+produces the production snapshot for that device kind.
 
 ``--list`` prints the registered grid with each case's DB status (exact hit
 / warm neighbor / cold, plus the stored record's search strategy) without
@@ -174,18 +177,19 @@ def _shard_filter(cases, smoke, wanted, only, shard, interpret: bool):
 
 def _analytic_cost_fn():
     """Deterministic stand-in for wall-clock measurement: the candidate's
-    roofline lower bound (:func:`repro.core.costs.roofline_terms` over its
-    compiled HLO).  Identical inputs give identical costs on every host and
+    roofline lower bound on a TPU v5e, whatever the host
+    (:func:`repro.core.costs.roofline_terms` over its compiled HLO).
+    Identical inputs give identical costs on every host and
     every run, which is what the fleet's shard-equivalence contract needs —
     a sharded sweep and an unsharded sweep must land on the same best
     points.  Candidates whose HLO defeats cost analysis fall back to a
     constant (still deterministic); relative quality between such ties is
     then decided by the search trajectory, which is equally deterministic."""
-    from repro.core import roofline_terms
+    from repro.core import TPU_V5E, roofline_terms
 
     def cost(ex, *args):
         try:
-            b = float(roofline_terms(ex, chips=1).bound_s)
+            b = float(roofline_terms(ex, chips=1, hw=TPU_V5E).bound_s)
         except Exception:
             b = 0.0
         return b if b > 0.0 else 1.0
@@ -328,6 +332,7 @@ def _launch_main(args, db, *, max_iter: int) -> int:
         journal = RunJournal(jpath)
 
     n_done = 0
+    n_failed = 0
     t_all = time.perf_counter()
     totals = {"measured": 0, "pruned": 0}
     sweep_span = obs.span("pretune", cases=len(cases), family="launch")
@@ -361,6 +366,7 @@ def _launch_main(args, db, *, max_iter: int) -> int:
                 journal.failed(key, "every candidate failed")
                 print(f"  launch/{arch}/{shape_name}: every candidate failed; "
                       f"nothing stored ({dt:.1f}s)", file=sys.stderr)
+                n_failed += 1
                 continue
             journal.commit(key, rec)
             sz = _space_size_str(space)
@@ -378,6 +384,9 @@ def _launch_main(args, db, *, max_iter: int) -> int:
             f"{totals['measured']} candidates scored ({mode}), "
             f"{totals['pruned']} constraint-pruned at zero cost"
         )
+        if n_failed:
+            print(f"pretune: {n_failed} launch case(s) committed no record", file=sys.stderr)
+            return 1
         return 0
     finally:
         sweep_span.__exit__(None, None, None)
@@ -404,7 +413,6 @@ def main(argv=None, prog: str = "repro.tuning.pretune") -> int:
     ap.add_argument("--num-opt", type=int, default=3, help="CSA coupled solvers")
     ap.add_argument("--max-iter", type=int, default=None, help="CSA iterations (default 2 smoke / 4)")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--no-interpret", action="store_true", help="run kernels compiled (TPU host)")
     ap.add_argument(
         "--jobs", type=int, default=None,
         help="concurrent AOT compiles per tuning round (default: REPRO_TUNE_JOBS or cpu count)",
@@ -478,6 +486,7 @@ def main(argv=None, prog: str = "repro.tuning.pretune") -> int:
 
     from repro import obs
     from repro.kernels.autotuned import exec_cache, registered, tune_call
+    from repro.kernels.ops import interpret_mode
     from repro.tuning import TuningDB, default_device
 
     if args.obs_dir:
@@ -488,7 +497,9 @@ def main(argv=None, prog: str = "repro.tuning.pretune") -> int:
     max_iter = args.max_iter if args.max_iter is not None else (2 if args.smoke else 4)
     db = TuningDB(args.db)
     backend, device_kind = default_device()
-    print(f"pretune: db={args.db} ({len(db)} records) device={backend}/{device_kind}")
+    interpret = interpret_mode()
+    print(f"pretune: db={args.db} ({len(db)} records) device={backend}/{device_kind}"
+          f"{' (interpret mode)' if interpret else ''}")
 
     if args.launch:
         return _launch_main(args, db, max_iter=max_iter)
@@ -511,14 +522,14 @@ def main(argv=None, prog: str = "repro.tuning.pretune") -> int:
         index, num = parse_shard(args.shard)
         total = len(cases)
         cases = _shard_filter(cases, args.smoke, wanted, args.only,
-                              (index, num), interpret=not args.no_interpret)
+                              (index, num), interpret=interpret)
         print(f"pretune: shard {index}/{num}: {len(cases)}/{total} cases")
         if not cases:
             # an empty shard is a fleet worker with nothing to do, not an error
             db.save()
             return 0
     if args.list_grid:
-        return _list_grid(cases, db, interpret=not args.no_interpret)
+        return _list_grid(cases, db, interpret=interpret)
 
     cost_fn = _analytic_cost_fn() if args.cost == "analytic" else None
 
@@ -552,6 +563,7 @@ def main(argv=None, prog: str = "repro.tuning.pretune") -> int:
 
     n_done = 0
     n_skipped = 0
+    n_failed = 0
     t_all = time.perf_counter()
     # aggregate measurement-engine counters across the sweep (run summary)
     totals = {"reps": 0, "warmup_reps": 0, "calibration_reps": 0,
@@ -563,7 +575,7 @@ def main(argv=None, prog: str = "repro.tuning.pretune") -> int:
     try:
         for name, label, build in cases:
             call_args = build()
-            key = _case_key(name, call_args, interpret=not args.no_interpret)
+            key = _case_key(name, call_args, interpret=interpret)
             if key.encode() in done_keys:
                 n_skipped += 1
                 continue
@@ -574,7 +586,7 @@ def main(argv=None, prog: str = "repro.tuning.pretune") -> int:
                 name,
                 *call_args,
                 db=db,
-                interpret=not args.no_interpret,
+                interpret=interpret,
                 num_opt=args.num_opt,
                 max_iter=max_iter,
                 seed=args.seed,
@@ -594,6 +606,7 @@ def main(argv=None, prog: str = "repro.tuning.pretune") -> int:
                 journal.failed(key, "every candidate failed")
                 print(f"  {name}/{label}: every candidate failed; nothing stored ({dt:.1f}s)",
                       file=sys.stderr)
+                n_failed += 1
                 continue
             journal.commit(key, rec)
             crashed = f" crashed={rec.crashed}" if rec.crashed else ""
@@ -626,6 +639,9 @@ def main(argv=None, prog: str = "repro.tuning.pretune") -> int:
                 f"{totals['pruned_roofline']} roofline-pruned, "
                 f"{totals['failed']} failed"
             )
+        if n_failed:
+            print(f"pretune: {n_failed} case(s) committed no record", file=sys.stderr)
+            return 1
         return 0
     finally:
         sweep_span.__exit__(None, None, None)
@@ -638,6 +654,9 @@ if __name__ == "__main__":
     # pretune``) so both spellings share one dispatch path
     import sys as _sys
 
+    from repro.compile_cache import use_compile_cache
     from repro.tune import main as _tune_main
+
+    use_compile_cache()
 
     raise SystemExit(_tune_main(["pretune", *_sys.argv[1:]]))

@@ -72,13 +72,12 @@ def signature_of(args: Sequence[Any] = (), kwargs: Optional[Mapping[str, Any]] =
 
 
 def default_device() -> tuple:
-    """(backend, device_kind) of the current process's default jax device."""
-    try:
-        import jax
+    """(backend, device_kind) of the current process's default jax device.
+    A backend that fails to start raises: a key for no known device would
+    file records under a context no other process can match."""
+    import jax
 
-        return str(jax.default_backend()), str(jax.devices()[0].device_kind)
-    except Exception:
-        return "none", "unknown"
+    return str(jax.default_backend()), str(jax.devices()[0].device_kind)
 
 
 def _canon(x: Any) -> str:

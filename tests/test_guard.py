@@ -292,12 +292,12 @@ def test_compile_fanout_fatal_raises_first_poison():
 
 
 # -------------------------------------------- tune_call under a fault plan
-def _matmul_args(n=192):
+def _matmul_args(n=512):
     import jax.numpy as jnp
 
-    # 192 keeps this grid (bm/bn/bk in {32, 64}) off every other test's
-    # shapes, so the process executable cache is cold and build-site
-    # faults actually reach the builds
+    # 512 keeps this grid (bm in {32, 256}, bn/bk in {128, 256}) off every
+    # other test's shapes, so the process executable cache is cold and
+    # build-site faults actually reach the builds
     return jnp.ones((n, n), jnp.float32), jnp.ones((n, n), jnp.float32)
 
 
@@ -316,11 +316,11 @@ def test_tune_call_completes_under_fault_plan():
     plan = FaultPlan(
         [
             FaultSpec(kind="hang", site="cost",
-                      match={"bm": 32, "bn": 32, "bk": 64}, seconds=0.3),
+                      match={"bm": 32, "bn": 128, "bk": 256}, seconds=0.3),
             FaultSpec(kind="transient", site="cost",
-                      match={"bm": 64, "bn": 32, "bk": 32}, times=2),
+                      match={"bm": 256, "bn": 128, "bk": 128}, times=2),
             FaultSpec(kind="crash", site="build",
-                      match={"bm": 32, "bn": 64, "bk": 32}, times=1),
+                      match={"bm": 32, "bn": 256, "bk": 128}, times=1),
         ]
     )
     # the faulted run goes FIRST: its builds are cache-cold, so the
@@ -549,3 +549,13 @@ def test_chaos_lane_env_plan_reaches_tune_call(monkeypatch):
     )
     assert rec is not None
     assert active_plan().count("cost") > 0  # the stragglers actually fired
+
+
+def test_sandboxed_probe_refuses_accelerator_backend(monkeypatch):
+    """A forked child cannot use the device its parent holds: off the CPU
+    backend the probe refuses with a clear error instead of hanging."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="forked child"):
+        sandboxed_probe(lambda: 1 + 1, timeout=30.0)

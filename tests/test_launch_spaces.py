@@ -238,7 +238,6 @@ def test_launch_keys_roundtrip_db_cli(tmp_path, capsys):
 def test_chunked_psum_matches_dense():
     code = """
 import jax, jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_mesh
 from repro.parallel.collectives import chunked_psum
@@ -249,8 +248,8 @@ g = jax.random.normal(jax.random.PRNGKey(0), (4, 37, 5))  # non-divisible size
 def red(chunk_bytes):
     def f(gl):
         return chunked_psum(gl[0], "data", chunk_bytes)
-    return shard_map(f, mesh=mesh, in_specs=(P("data", None, None),),
-                     out_specs=P(), check_rep=False)(g)
+    return jax.shard_map(f, mesh=mesh, in_specs=(P("data", None, None),),
+                     out_specs=P(), check_vma=False)(g)
 
 exact = jnp.sum(g, axis=0)
 for cb in (64, 256, 10**9):  # many chunks, a few, and one monolithic psum

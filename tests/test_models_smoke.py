@@ -178,3 +178,12 @@ def test_param_count_sane():
 def test_vocab_padding():
     cfg = configs.get("seamless_m4t_large_v2")
     assert cfg.padded_vocab % 256 == 0 and cfg.padded_vocab >= cfg.vocab_size
+
+
+def test_depth_cut_keeps_published_widths():
+    full = configs.get("qwen2_7b")
+    cut = configs.get("qwen2_7b", n_layers=8)
+    assert (cut.n_layers, cut.n_groups) == (8, 8)
+    for f in ("d_model", "n_heads", "n_kv_heads", "d_head", "d_ff", "vocab_size", "group"):
+        assert getattr(cut, f) == getattr(full, f), f
+    assert configs.get("qwen2_7b") is full

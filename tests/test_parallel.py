@@ -73,7 +73,6 @@ print("OK")
 def test_int8_psum_and_topk():
     code = """
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_mesh
 from repro.parallel.collectives import int8_psum, topk_psum
@@ -83,7 +82,7 @@ g = jax.random.normal(jax.random.PRNGKey(0), (4, 64))
 
 def f8(gl):
     return int8_psum(gl[0], "data")
-out = shard_map(f8, mesh=mesh, in_specs=(P("data", None),), out_specs=P(), check_rep=False)(g)
+out = jax.shard_map(f8, mesh=mesh, in_specs=(P("data", None),), out_specs=P(), check_vma=False)(g)
 exact = jnp.sum(g, axis=0)
 rel = float(jnp.max(jnp.abs(out - exact)) / (jnp.max(jnp.abs(exact)) + 1e-9))
 assert rel < 0.05, rel
@@ -92,13 +91,13 @@ def ftk(gl, el):
     r, ne = topk_psum(gl[0], "data", 0.25, el[0])
     return r, ne[None]
 err0 = jnp.zeros((4, 64))
-out, ne = shard_map(ftk, mesh=mesh, in_specs=(P("data", None), P("data", None)),
-                    out_specs=(P(), P("data", None)), check_rep=False)(g, err0)
+out, ne = jax.shard_map(ftk, mesh=mesh, in_specs=(P("data", None), P("data", None)),
+                    out_specs=(P(), P("data", None)), check_vma=False)(g, err0)
 # error feedback: sparse + residual == original (per shard)
 recon = out  # sum of sparse parts
 # after two rounds with error feedback the cumulative reduction approaches exact
-r2, ne2 = shard_map(ftk, mesh=mesh, in_specs=(P("data", None), P("data", None)),
-                    out_specs=(P(), P("data", None)), check_rep=False)(jnp.zeros_like(g), ne)
+r2, ne2 = jax.shard_map(ftk, mesh=mesh, in_specs=(P("data", None), P("data", None)),
+                    out_specs=(P(), P("data", None)), check_vma=False)(jnp.zeros_like(g), ne)
 total = out + r2
 gap1 = float(jnp.linalg.norm(out - exact))
 gap2 = float(jnp.linalg.norm(total - exact))

@@ -790,3 +790,49 @@ def test_pretune_only_tunes_single_case(tmp_path):
     db = DB(db_path)
     assert len(db) == 1
     assert db.records()[0].key.name == "matmul"
+
+
+def test_pretune_exits_nonzero_when_a_case_commits_nothing(tmp_path, monkeypatch):
+    """A case whose every candidate failed is an error of the sweep, not a
+    line on stderr."""
+    import importlib
+
+    from repro.tuning.pretune import main as pretune_main
+
+    autotuned_mod = importlib.import_module("repro.kernels.autotuned")
+    monkeypatch.setattr(autotuned_mod, "tune_call", lambda *a, **k: None)
+    rc = pretune_main(["--db", str(tmp_path / "db.json"), "--smoke", "--only", "matmul/64*"])
+    assert rc == 1
+
+
+def test_compile_cache_follows_env_else_checkout(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, when set, is where compiled entries go and
+    nothing else is set in code; otherwise the fixed directory in the checkout."""
+    import os
+    import subprocess
+    import sys
+
+    from repro.compile_cache import DEFAULT_DIR
+
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro.compile_cache import use_compile_cache\n"
+        "print(use_compile_cache())\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+        "import sys\n"
+        "if len(sys.argv) > 1: jax.jit(lambda x: jnp.sin(x) @ x)(jnp.ones((64, 64))).block_until_ready()\n"
+    )
+    src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu",
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    cache = tmp_path / "cache"
+    env["JAX_COMPILATION_CACHE_DIR"] = str(cache)
+    out = subprocess.run([sys.executable, "-c", code, "compile"], env=env, capture_output=True,
+                         text=True, timeout=300, check=True).stdout.split()
+    assert out == [str(cache), str(cache)]
+    assert any(cache.iterdir())  # the compiled entry landed there
+    del env["JAX_COMPILATION_CACHE_DIR"]
+    out = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True,
+                         text=True, timeout=300, check=True).stdout.split()
+    assert out == [DEFAULT_DIR, DEFAULT_DIR]
+    assert DEFAULT_DIR.endswith(".jax_cache")

@@ -357,3 +357,51 @@ def test_tuned_step_warm_start(tmp_path):
     assert ts2.best_knobs == ts.best_knobs
     out = ts2(jnp.zeros((4,)))  # runs the stored-best step directly
     assert out.shape == (4,)
+
+
+def test_default_device_raises_when_backend_fails(monkeypatch):
+    """No silent ('none', 'unknown') key: a backend that fails to start is an error."""
+    import jax
+
+    from repro.tuning import default_device
+
+    def broken():
+        raise RuntimeError("backend failed to start")
+
+    monkeypatch.setattr(jax, "devices", broken)
+    with pytest.raises(RuntimeError, match="backend failed"):
+        default_device()
+
+
+def test_roofline_peaks_only_for_known_device_kinds():
+    """Peaks come from the table keyed by device_kind; any other kind gets no
+    roofline bound, never v5e's."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import PEAKS, TPU_V5E, peaks_for
+    from repro.kernels.autotuned import _roofline_bound
+
+    assert peaks_for("TPU v5 lite") is TPU_V5E and PEAKS["TPU v5 lite"].peak_flops == 197e12
+    assert peaks_for("cpu") is None
+    ex = jax.jit(lambda x: x @ x).lower(jnp.ones((64, 64))).compile()
+    assert _roofline_bound(ex) is None  # the CPU device has no published peaks
+
+
+def test_matmul_space_offers_only_lane_legal_tiles():
+    """bn/bk are the last dims of TPU blocks: multiples of 128, or the full dim."""
+    import jax.numpy as jnp
+
+    from repro.kernels.autotuned import get_spec
+
+    spec = get_spec("matmul")
+    for m, k, n in [(2048, 3584, 18944), (64, 64, 64), (96, 192, 384)]:
+        a, b = jnp.zeros((m, k)), jnp.zeros((k, n))
+        space = spec.space(a, b)
+        dims = {d.name: d for d in space.dims}
+        defaults = spec.defaults(a, b)
+        for name, full in (("bn", n), ("bk", k)):
+            for z in (-1.0, 0.0, 1.0):
+                v = dims[name].decode(z)
+                assert v == full or (v % 128 == 0 and full % v == 0), (name, full, v)
+            assert defaults[name] == full or defaults[name] % 128 == 0
