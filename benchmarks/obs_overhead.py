@@ -2,7 +2,8 @@
 
 The observability layer's contract is that it is a **sidecar**: disabled, an
 instrumentation site costs one attribute check (the tracer's ``enabled``
-flag, the event module's ``_SINK is None`` early-out); enabled, the fsynced
+flag, the event module's ``_SINK is None`` early-out) and a profiler
+annotation that reads no clock while no profiler session records; enabled, the fsynced
 event stream and span bookkeeping ride along without distorting the search.
 This benchmark measures both prices on ``tuning_throughput``'s contexts,
 but with a **deterministic** ``cost_fn`` instead of wall-clock costs: a

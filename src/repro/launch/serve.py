@@ -34,7 +34,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import configs
+from repro import configs, obs
 from repro.core import ChoiceDim, SearchSpace
 from repro.models import ExecConfig, Model
 from repro.runtime import ContextRouter
@@ -58,18 +58,33 @@ def replay_decode_k(db, key, *, gen: int, default: int = 1) -> int:
 
 def decode_chunk(model, k: int):
     """The jitted decode of ``k`` greedy tokens in one dispatched scan:
-    ``(params, token, states, pos) -> (token, states, pos, tokens)``."""
-    @jax.jit
+    ``(params, token, states, pos) -> (token, states, pos, tokens)``.  Its
+    HLO module is ``jit_decode_k<k>``, so a profiler trace tells the chunk
+    sizes apart."""
     def run(params, token, states, pos):
         def body(carry, _):
             token, states, pos = carry
             lg, states = model.decode_step(params, token, states, pos)
-            nxt = jnp.argmax(lg, -1).astype(jnp.int32)[:, None]
+            with jax.named_scope("sample"):
+                nxt = jnp.argmax(lg, -1).astype(jnp.int32)[:, None]
             return (nxt, states, pos + 1), nxt
         (token, states, pos), toks = jax.lax.scan(
             body, (token, states, pos), None, length=k)
         return token, states, pos, toks
-    return run
+    run.__name__ = f"decode_k{k}"
+    return jax.jit(run)
+
+
+def run_chunk(fn, params, token, states, pos):
+    """Run one decode chunk ``fn`` (a :func:`decode_chunk` program) and wait
+    for its tokens: ``(token, states, pos, tokens)``.  The call until it
+    returns is the ``serve.dispatch`` span, the wait for the tokens
+    ``serve.wait``."""
+    with obs.span("serve.dispatch", cat="serve"):
+        token, states, pos, toks = fn(params, token, states, pos)
+    with obs.span("serve.wait", cat="serve"):
+        jax.block_until_ready(toks)
+    return token, states, pos, toks
 
 
 def parser() -> argparse.ArgumentParser:
@@ -107,7 +122,6 @@ def main():
     if args.tiny and args.layers is not None:
         ap.error("--layers cuts the published config; it does not apply with --tiny")
 
-    from repro import obs
     from repro.compile_cache import use_compile_cache
 
     use_compile_cache()
@@ -189,8 +203,7 @@ def _serve(args) -> dict:
         while emitted < args.gen:
             k = min(k_static, args.gen - emitted)
             fn = fn_static if k == k_static else tail_fns.setdefault(k, make_multi(k))
-            token, states, pos, toks = fn(params, token, states, pos)
-            jax.block_until_ready(toks)
+            token, states, pos, toks = run_chunk(fn, params, token, states, pos)
             emitted += k
         wall = time.perf_counter() - t0
         print(f"decode: {emitted} tok/seq x {B} in {wall*1e3:.0f} ms "
@@ -243,19 +256,16 @@ def _serve(args) -> dict:
             fn = tuner.executable_for({"k": k}) if k in ks else None
             if fn is None:
                 fn = tail_fns.setdefault(k, make_multi(k))
-            token, states, pos, toks = fn(params, token, states, pos)
-            jax.block_until_ready(toks)
+            token, states, pos, toks = run_chunk(fn, params, token, states, pos)
             emitted += k
             continue
         decision = router.begin("serve/decode_k")
         k = int(decision.point["k"])  # always <= ks[-1] <= rem here
         tc = time.perf_counter()
-        if decision.executable is not None:
-            token, states, pos, toks = decision.executable(params, token, states, pos)
-        else:  # cold exploit before the background build lands
+        fn = decision.executable
+        if fn is None:  # cold exploit before the background build lands
             fn = tail_fns.setdefault(k, make_multi(k))
-            token, states, pos, toks = fn(params, token, states, pos)
-        jax.block_until_ready(toks)
+        token, states, pos, toks = run_chunk(fn, params, token, states, pos)
         router.observe(decision, (time.perf_counter() - tc) / k)
         emitted += k
     wall = time.perf_counter() - t0

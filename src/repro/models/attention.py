@@ -46,6 +46,7 @@ def init_attn_cache(cfg: ModelConfig, batch: int, max_len: int, *, window: int =
     }
 
 
+@jax.named_scope("kv_write")
 def _ring_write(cache: dict, kc, vc, q_pos) -> dict:
     """Store the n entries at consecutive absolute positions ``q_pos`` in the
     ring of W slots, entry i at slot (q_pos[0] + i) mod W.  n == W rewrites
@@ -142,6 +143,7 @@ def cross_kv(cfg: ModelConfig, p: dict, ctx: jnp.ndarray) -> Tuple[jnp.ndarray, 
     return k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
 
 
+@jax.named_scope("attn")
 def attn_apply(
     cfg: ModelConfig,
     p: dict,

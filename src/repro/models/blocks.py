@@ -35,9 +35,10 @@ def _ffn_params(rng, cfg: ModelConfig):
 
 
 def _apply_ffn(cfg: ModelConfig, p, x):
-    if cfg.ffn == "moe":
-        return moe_apply(cfg, p, x)
-    return ffn_apply(cfg.ffn, p, x), jnp.zeros((), jnp.float32)
+    with jax.named_scope("ffn"):
+        if cfg.ffn == "moe":
+            return moe_apply(cfg, p, x)
+        return ffn_apply(cfg.ffn, p, x), jnp.zeros((), jnp.float32)
 
 
 def init_block(kind: str, rng, cfg: ModelConfig) -> dict:

@@ -29,6 +29,7 @@ def norm_init(kind: str, d: int) -> dict:
     return p
 
 
+@jax.named_scope("norm")
 def norm_apply(kind: str, p: dict, x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     dt = x.dtype
     x = x.astype(jnp.float32)

@@ -99,12 +99,13 @@ class Model:
         new_states = []
         for si, (kinds, ng) in enumerate(stage_defs):
             body = self.make_stage_body(kinds, q_pos=q_pos, ctx=ctx, mode=mode, causal=causal)
-            (x, aux), st_out = jax.lax.scan(
-                body,
-                (x, aux),
-                (stages_params[si], states[si]),
-                unroll=max(1, min(ec.scan_unroll, ng)),
-            )
+            with jax.named_scope("layers"):
+                (x, aux), st_out = jax.lax.scan(
+                    body,
+                    (x, aux),
+                    (stages_params[si], states[si]),
+                    unroll=max(1, min(ec.scan_unroll, ng)),
+                )
             new_states.append(st_out)
         return x, new_states, aux
 
@@ -146,8 +147,8 @@ class Model:
 
     # ------------------------------------------------------------ embeddings
     def embed(self, params, tokens):
-        x = params["embed"]["table"].astype(jnp.dtype(self.cfg.compute_dtype))[tokens]
-        return x
+        with jax.named_scope("embed"):
+            return params["embed"]["table"].astype(jnp.dtype(self.cfg.compute_dtype))[tokens]
 
     def head_weights(self, params):
         if self.cfg.tie_embeddings:
@@ -155,8 +156,8 @@ class Model:
         return params["lm_head"]["w"]
 
     def logits(self, params, x):
-        w = self.head_weights(params).astype(x.dtype)
-        return x @ w
+        with jax.named_scope("lm_head"):
+            return x @ self.head_weights(params).astype(x.dtype)
 
     # --------------------------------------------------------------- encoder
     def encode(self, params, frames):

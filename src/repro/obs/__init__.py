@@ -1,10 +1,12 @@
-"""repro.obs — zero-dependency observability for the tuning stack.
+"""repro.obs — observability for the tuning and serving stack.
 
 Four pieces, one switch:
 
 * :mod:`repro.obs.trace` — nestable spans (search → round → compile /
-  measure / commit), thread-pool and fork aware, exported as Chrome trace
-  JSON (``chrome://tracing`` / Perfetto).
+  measure / commit; the serving path's dispatch, wait and tuner turns),
+  thread-pool and fork aware, exported as Chrome trace JSON
+  (``chrome://tracing`` / Perfetto) and always annotated on the JAX
+  profiler's trace.
 * :mod:`repro.obs.metrics` — process-level counters / gauges / fixed-bucket
   histograms; always on (increments are too cheap to gate).
 * :mod:`repro.obs.events` — a durable JSONL stream of every tuning decision,
@@ -13,10 +15,11 @@ Four pieces, one switch:
   library code uses instead of ad-hoc ``print``.
 
 Observability is a **sidecar**: the tuning DB schema, committed records and
-search trajectories are identical with it on or off.  Tracing and the event
-stream are opt-in via :func:`configure` — the CLIs wire ``--obs-dir`` (or
-the ``REPRO_OBS`` env var) to it — and every instrumentation site costs a
-single attribute check while disabled.
+search trajectories are identical with it on or off.  The JSON trace and
+the event stream are opt-in via :func:`configure` — the CLIs wire
+``--obs-dir`` (or the ``REPRO_OBS`` env var) to it; while they are off a
+span is a profiler annotation that reads no clock unless a profiler
+session records.
 
     from repro import obs
     obs.configure("artifacts/obs")      # or: REPRO_OBS=artifacts/obs
@@ -25,9 +28,8 @@ single attribute check while disabled.
                                         # (events.jsonl streamed all along)
 
 Serving at scale: ``REPRO_OBS_SAMPLE=0.01`` (or ``configure(...,
-sample=0.01)``) decimates the high-rate per-request artifacts — serving
-``request`` spans and ``explore_rep`` events — to 1-in-N with a
-deterministic counter stride.  Structural spans and accounting events are
+sample=0.01)``) decimates the high-rate ``explore_rep`` events to 1-in-N
+with a deterministic counter stride.  Spans and accounting events are
 never sampled; the sink reports what it dropped in a close-time
 ``sampling_summary`` so :func:`completeness` still balances.
 """
@@ -70,7 +72,7 @@ __all__ = [
 
 _OBS_DIR: Optional[str] = None
 
-#: env var: sample rate in (0, 1] for per-request spans + events
+#: env var: sample rate in (0, 1] for ``explore_rep`` events
 ENV_OBS_SAMPLE = "REPRO_OBS_SAMPLE"
 
 TRACE_FILE = "trace.json"
@@ -94,7 +96,7 @@ def configure(directory: Optional[str], *, sample: Optional[float] = None) -> bo
     whether observability is enabled afterwards.  Idempotent for the same
     directory; a new directory re-points the sink and resets the tracer.
     ``sample`` (default: the ``REPRO_OBS_SAMPLE`` env var, else keep
-    everything) decimates per-request spans/events to roughly that
+    everything) decimates ``explore_rep`` events to roughly that
     fraction."""
     global _OBS_DIR
     if not directory:
@@ -108,8 +110,6 @@ def configure(directory: Optional[str], *, sample: Optional[float] = None) -> bo
             sample = float(raw)
     if _OBS_DIR == directory:
         if sample is not None:
-            t = tracer()
-            t.set_sample_rate(sample)
             s = events.sink()
             if s is not None:
                 s.set_sample_rate(sample)
@@ -123,7 +123,6 @@ def configure(directory: Optional[str], *, sample: Optional[float] = None) -> bo
     t.enable()
     sink = EventSink(os.path.join(directory, EVENTS_FILE))
     if sample is not None:
-        t.set_sample_rate(sample)
         sink.set_sample_rate(sample)
     events.set_sink(sink)
     return True
@@ -131,8 +130,8 @@ def configure(directory: Optional[str], *, sample: Optional[float] = None) -> bo
 
 def configure_from_env() -> bool:
     """Opt in via ``REPRO_OBS=<dir>`` (how ``serve``/``train``/``pretune``
-    pick it up without a flag); ``REPRO_OBS_SAMPLE`` tunes request-level
-    sampling."""
+    pick it up without a flag); ``REPRO_OBS_SAMPLE`` tunes ``explore_rep``
+    event sampling."""
     return configure(os.environ.get("REPRO_OBS") or None)
 
 

@@ -1,4 +1,4 @@
-"""Nestable spans over the tuning stack, exported as Chrome trace JSON.
+"""Nestable spans over the tuning and serving stack, on two sinks.
 
 The span tree mirrors the search structure::
 
@@ -8,11 +8,34 @@ The span tree mirrors the search structure::
        ├─ measure               the round's repetition racing
        └─ commit                DB keep-better commit
 
+and the serving path (``launch/serve.run_chunk``, ``runtime/online``,
+``runtime/drift``)::
+
+    serve.dispatch / serve.wait     one decode chunk: the call, the wait
+    tuner.begin                     one routing decision
+    tuner.observe                   one served cost fed back
+    ├─ tuner.tell                   the cost fed to the search
+    ├─ tuner.commit                 the search converged (serving thread)
+    ├─ drift.observe                the drift detector's look
+    └─ tuner.drift_reset            a re-tune after drift
+
+Two sinks, one API (:func:`span`):
+
+* **the JAX profiler**: every span also enters
+  ``jax.profiler.TraceAnnotation(name)``, so while a profiler session
+  records it appears on the ``/host:CPU`` plane of the ``.xplane.pb``, on
+  the clock the device trace is aligned to.  With no session recording the
+  annotation reads no clock.
+* **Chrome trace JSON** (``--obs-dir`` / ``REPRO_OBS``): timestamps are
+  ``time.perf_counter_ns`` offsets from a per-process epoch, exported as
+  "complete" ``ph: "X"`` events for ``chrome://tracing`` and
+  https://ui.perfetto.dev; the wall-clock anchor is kept once per export
+  for correlating with the event stream.
+
 Design constraints, in order:
 
-* **default-off and cheap**: every instrumentation site goes through
-  :func:`span`, which returns a shared no-op context manager while the
-  tracer is disabled — no allocation, no clock read, no lock.
+* **cheap when off**: with both sinks off, :func:`span` allocates the
+  annotation object and nothing else: no clock read, no lock.
 * **thread-safe and pool-aware**: each thread keeps its own span stack, so
   concurrent workers can't cross-nest.  ``ThreadPoolExecutor`` does *not*
   carry the submitting thread's context into workers, so cross-thread
@@ -23,18 +46,6 @@ Design constraints, in order:
 * **fork-aware**: a forked child (``sandbox_first_touch`` probes) must not
   re-export the parent's buffered spans; ``os.register_at_fork`` clears the
   child's buffer and stacks.
-* **monotonic clocks**: timestamps are ``time.perf_counter_ns`` offsets from
-  a per-process epoch — immune to wall-clock steps; the wall-clock anchor is
-  kept once per export for correlating with the event stream.
-* **samplable request spans**: per-request serving spans (opened with
-  ``sampled=True``) can be decimated with :meth:`Tracer.set_sample_rate` —
-  a deterministic 1-in-N counter stride, not an RNG, so a replayed workload
-  keeps the same spans.  Sampled-out spans cost one counter tick and return
-  the shared no-op; the ``sampled_out`` counter keeps the bookkeeping
-  honest.  Structural spans (search/round/compile) are never sampled out.
-
-Export is the Chrome trace ("complete" ``ph: "X"`` events) consumed by
-``chrome://tracing`` and https://ui.perfetto.dev.
 """
 from __future__ import annotations
 
@@ -45,6 +56,8 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "Span",
     "Tracer",
@@ -52,7 +65,6 @@ __all__ = [
     "span",
     "current_span",
     "export_chrome",
-    "set_sample_rate",
 ]
 
 
@@ -91,35 +103,24 @@ class Span:
         return f"<Span {self.name} id={self.span_id} parent={self.parent_id} {state}>"
 
 
-class _NullSpanContext:
-    """The disabled-tracer fast path: one shared, stateless no-op."""
-
-    __slots__ = ()
-
-    def __enter__(self) -> None:
-        return None
-
-    def __exit__(self, *exc) -> bool:
-        return False
-
-
-_NULL_SPAN = _NullSpanContext()
-
-
 class _SpanContext:
-    __slots__ = ("_tracer", "_span", "_explicit_parent")
+    """An open span on both sinks: the tracer's stack and the profiler."""
 
-    def __init__(self, tracer: "Tracer", span: Span, explicit_parent: bool) -> None:
+    __slots__ = ("_tracer", "_span", "_annotation")
+
+    def __init__(self, tracer: "Tracer", span: Span) -> None:
         self._tracer = tracer
         self._span = span
-        self._explicit_parent = explicit_parent
+        self._annotation = TraceAnnotation(span.name)
 
     def __enter__(self) -> Span:
+        self._annotation.__enter__()
         self._tracer._push(self._span)
         return self._span
 
     def __exit__(self, *exc) -> bool:
         self._tracer._pop(self._span)
+        self._annotation.__exit__(*exc)
         return False
 
 
@@ -136,11 +137,6 @@ class Tracer:
         self._epoch_ns = time.perf_counter_ns()
         self._epoch_unix = time.time()
         self.dropped = 0  # spans discarded after a fork
-        # request-span sampling: keep 1 in _sample_stride of sampled=True
-        # spans (deterministic counter, no RNG — replays keep the same spans)
-        self._sample_stride = 1
-        self._sample_counter = itertools.count(0)
-        self.sampled_out = 0
 
     # ----------------------------------------------------------- lifecycle
     def enable(self) -> None:
@@ -156,18 +152,6 @@ class Tracer:
         self._local = threading.local()
         self._epoch_ns = time.perf_counter_ns()
         self._epoch_unix = time.time()
-        self._sample_stride = 1
-        self._sample_counter = itertools.count(0)
-        self.sampled_out = 0
-
-    def set_sample_rate(self, rate: float) -> None:
-        """Keep roughly ``rate`` of ``sampled=True`` spans (1-in-N stride,
-        ``N = round(1/rate)``).  ``rate >= 1`` keeps everything."""
-        rate = float(rate)
-        if not rate > 0.0:
-            raise ValueError(f"sample rate must be > 0, got {rate}")
-        self._sample_stride = max(1, round(1.0 / rate)) if rate < 1.0 else 1
-        self._sample_counter = itertools.count(0)
 
     def _after_fork(self) -> None:
         # the child inherits the parent's buffer; it must not re-export it
@@ -196,21 +180,14 @@ class Tracer:
         cat: str = "tuning",
         *,
         parent: Optional[Span] = None,
-        sampled: bool = False,
         **args: Any,
     ):
         """Context manager opening a child of ``parent`` (default: this
-        thread's current span).  Returns a shared no-op while disabled.
-        ``sampled=True`` marks a high-rate per-request span subject to
-        :meth:`set_sample_rate` decimation."""
+        thread's current span).  While the tracer is disabled it is the
+        profiler annotation alone."""
         if not self.enabled:
-            return _NULL_SPAN
-        if sampled and self._sample_stride > 1:
-            if next(self._sample_counter) % self._sample_stride:
-                self.sampled_out += 1
-                return _NULL_SPAN
-        explicit = parent is not None
-        if not explicit:
+            return TraceAnnotation(name)
+        if parent is None:
             parent = self.current()
         sid = next(self._ids)  # itertools.count: atomic under the GIL
         s = Span(
@@ -223,7 +200,7 @@ class Tracer:
             t0_ns=time.perf_counter_ns() - self._epoch_ns,
             args=args or None,
         )
-        return _SpanContext(self, s, explicit)
+        return _SpanContext(self, s)
 
     def wrap(
         self,
@@ -234,8 +211,9 @@ class Tracer:
     ) -> Callable:
         """Wrap ``fn`` so it runs under a child span of the *submitting*
         thread's current span — the ``pool.submit(tracer.wrap(f, "compile"))``
-        pattern.  A no-op passthrough while disabled."""
-        if not self.enabled:
+        pattern.  A passthrough while neither the tracer nor a profiler
+        session records."""
+        if not (self.enabled or TraceAnnotation.is_enabled()):
             return fn
         parent = self.current()
 
@@ -324,16 +302,10 @@ def span(
     cat: str = "tuning",
     *,
     parent: Optional[Span] = None,
-    sampled: bool = False,
     **args,
 ):
-    """Open a span on the process tracer (no-op context while disabled)."""
-    return _TRACER.span(name, cat, parent=parent, sampled=sampled, **args)
-
-
-def set_sample_rate(rate: float) -> None:
-    """Set the process tracer's request-span sample rate."""
-    _TRACER.set_sample_rate(rate)
+    """Open a span on the process tracer and the profiler."""
+    return _TRACER.span(name, cat, parent=parent, **args)
 
 
 def current_span() -> Optional[Span]:

@@ -26,9 +26,10 @@ from __future__ import annotations
 
 import math
 from collections import deque
+from typing import Optional
 
 from repro.obs import metrics as _metrics
-from typing import Optional
+from repro.obs.trace import tracer as _tracer
 
 __all__ = ["DriftDetector"]
 
@@ -139,7 +140,10 @@ class DriftDetector:
     # ----------------------------------------------------------- observe
     def observe(self, cost: float) -> int:
         """Feed one exploit-cost sample; returns the escalation level."""
-        cost = float(cost)
+        with _tracer().span("drift.observe", cat="serve"):
+            return self._observe(float(cost))
+
+    def _observe(self, cost: float) -> int:
         if not math.isfinite(cost):
             return 0
         self.observed += 1

@@ -489,7 +489,7 @@ class OnlineTuner:
         executable cache (shape-exact) and are what background builds
         compile against.  ``tenant`` names the request stream for per-tenant
         ε-credit accounting (``None`` = unattributed, global budget only)."""
-        with _tracer().span("request", cat="serve", sampled=True, ctx=self.name):
+        with _tracer().span("tuner.begin", cat="serve", ctx=self.name):
             with self._lock:
                 return self._begin_locked(args, kwargs, tenant, _force_explore)
 
@@ -552,24 +552,29 @@ class OnlineTuner:
         ``observe`` is discarded as stale — feeding it would attribute the
         old candidate's cost to the new one.  Returns the drift level acted
         on this call (0 = none)."""
-        cost = float(cost)
-        with self._lock:
-            at = self.at
-            if decision.kind == EXPLORE:
-                if self._explore_inflight > 0:  # lands from the begin() gap
-                    self._explore_inflight -= 1
-                if self.breaker is not None:
-                    if np.isfinite(cost):
-                        self.breaker.record_success()
-                    else:
-                        self.breaker.record_failure()
-                if at.finished:
-                    # decided after this decision was issued (sibling stream
-                    # finished the search / absorbed the candidate)
-                    self.stats_["stale_explore_reps"] += 1
-                    return 0
-                _events.emit("explore_rep", name=self.name,
-                             point=dict(decision.point), cost=cost)
+        with _tracer().span("tuner.observe", cat="serve", ctx=self.name):
+            with self._lock:
+                return self._observe_locked(decision, float(cost))
+
+    def _observe_locked(self, decision: Decision, cost: float) -> int:
+        tr = _tracer()
+        at = self.at
+        if decision.kind == EXPLORE:
+            if self._explore_inflight > 0:  # lands from the begin() gap
+                self._explore_inflight -= 1
+            if self.breaker is not None:
+                if np.isfinite(cost):
+                    self.breaker.record_success()
+                else:
+                    self.breaker.record_failure()
+            if at.finished:
+                # decided after this decision was issued (sibling stream
+                # finished the search / absorbed the candidate)
+                self.stats_["stale_explore_reps"] += 1
+                return 0
+            _events.emit("explore_rep", name=self.name,
+                         point=dict(decision.point), cost=cost)
+            with tr.span("tuner.tell", cat="serve"):
                 if self.measure is None:
                     if at.space.key(decision.point) != at.space.key(at.point):
                         self.stats_["stale_explore_reps"] += 1
@@ -579,14 +584,16 @@ class OnlineTuner:
                     at.exec(cost)
                 else:
                     self._feed_rep(cost, decision)
-                if at.finished:
+            if at.finished:
+                with tr.span("tuner.commit", cat="serve"):
                     self._on_search_complete()
-                return 0
-            if self.drift is not None and at.finished:
-                level = self.drift.observe(cost)
-                if level > 0:
+            return 0
+        if self.drift is not None and at.finished:
+            level = self.drift.observe(cost)
+            if level > 0:
+                with tr.span("tuner.drift_reset", cat="serve", level=level):
                     self._drift_reset(level)
-                    return level
+                return level
         return 0
 
     # ------------------------------------------------- multi-rep exploration
