@@ -60,11 +60,19 @@ def decode_chunk(model, k: int):
     """The jitted decode of ``k`` greedy tokens in one dispatched scan:
     ``(params, token, states, pos) -> (token, states, pos, tokens)``.  Its
     HLO module is ``jit_decode_k<k>``, so a profiler trace tells the chunk
-    sizes apart."""
+    sizes apart.
+
+    The program does not own its input ``states``, so it cannot write them
+    in place.  A lone step reads them and writes a fresh buffer whole.  A
+    chunk of k > 1 runs every step in place (``Model.decode_step(in_place=
+    True)``), one cache slot a layer, on the copy XLA makes of the input at
+    entry: the whole state is written once a chunk, not k times."""
+    in_place = k > 1
+
     def run(params, token, states, pos):
         def body(carry, _):
             token, states, pos = carry
-            lg, states = model.decode_step(params, token, states, pos)
+            lg, states = model.decode_step(params, token, states, pos, in_place=in_place)
             with jax.named_scope("sample"):
                 nxt = jnp.argmax(lg, -1).astype(jnp.int32)[:, None]
             return (nxt, states, pos + 1), nxt
@@ -79,11 +87,16 @@ def run_chunk(fn, params, token, states, pos):
     """Run one decode chunk ``fn`` (a :func:`decode_chunk` program) and wait
     for its tokens: ``(token, states, pos, tokens)``.  The call until it
     returns is the ``serve.dispatch`` span, the wait for the tokens
-    ``serve.wait``."""
+    ``serve.wait``.  Counts the chunk's steps that wrote a fresh state
+    buffer whole (a lone step) and those that ran in place (every step of a
+    longer chunk) as ``serve.decode_steps.fresh`` and
+    ``serve.decode_steps.in_place``."""
     with obs.span("serve.dispatch", cat="serve"):
         token, states, pos, toks = fn(params, token, states, pos)
     with obs.span("serve.wait", cat="serve"):
         jax.block_until_ready(toks)
+    k = toks.shape[0]
+    obs.counter("serve.decode_steps.in_place" if k > 1 else "serve.decode_steps.fresh").inc(k)
     return token, states, pos, toks
 
 

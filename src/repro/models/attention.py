@@ -47,25 +47,33 @@ def init_attn_cache(cfg: ModelConfig, batch: int, max_len: int, *, window: int =
 
 
 @jax.named_scope("kv_write")
-def _ring_write(cache: dict, kc, vc, q_pos) -> dict:
+def _ring_write(cache: dict, kc, vc, q_pos, layer=None) -> dict:
     """Store the n entries at consecutive absolute positions ``q_pos`` in the
     ring of W slots, entry i at slot (q_pos[0] + i) mod W.  n == W rewrites
     the whole ring (a rotation); n < W is one contiguous write, which never
     wraps here: a prefill starts at position 0 and a decode step writes one
-    slot.  Slice updates rather than a scatter: the TPU compiler aborts on
-    the scatter form of this write."""
-    W = cache["k"].shape[2]
+    slot.  With ``layer``, ``cache`` is a stage's stack of caches (leading
+    layer dim) and the entries go to that layer's ring, in place.  Slice
+    updates rather than a scatter: the TPU compiler aborts on the scatter
+    form of this write."""
+    W = cache["pos"].shape[-1]
     s0 = jnp.mod(q_pos[0], W).astype(jnp.int32)
     pos = q_pos.astype(jnp.int32)
-    if kc.shape[2] == W:
+    if layer is None and kc.shape[2] == W:
         return {"k": jnp.roll(kc.astype(cache["k"].dtype), s0, axis=2),
                 "v": jnp.roll(vc.astype(cache["v"].dtype), s0, axis=2),
                 "pos": jnp.roll(pos, s0)}
     z = jnp.int32(0)
+    lead = () if layer is None else (layer,)
+
+    def put(buf, new, start):
+        return jax.lax.dynamic_update_slice(
+            buf, new.astype(buf.dtype).reshape((1,) * len(lead) + new.shape), lead + start)
+
     return {
-        "k": jax.lax.dynamic_update_slice(cache["k"], kc.astype(cache["k"].dtype), (z, z, s0, z)),
-        "v": jax.lax.dynamic_update_slice(cache["v"], vc.astype(cache["v"].dtype), (z, z, s0, z)),
-        "pos": jax.lax.dynamic_update_slice(cache["pos"], pos, (s0,)),
+        "k": put(cache["k"], kc, (z, z, s0, z)),
+        "v": put(cache["v"], vc, (z, z, s0, z)),
+        "pos": put(cache["pos"], pos, (s0,)),
     }
 
 
@@ -156,13 +164,16 @@ def attn_apply(
     window: int = 0,
     rope: bool = True,
     exec_cfg: ExecConfig = ExecConfig(),
+    layer=None,
 ) -> Tuple[jnp.ndarray, Optional[dict]]:
     """Self- or cross-attention with optional KV cache.
 
     Modes:
       * train/encode: ``cache=None, kv=None`` — full-sequence self-attention.
       * prefill:      ``cache=empty`` — fills the cache, returns outputs.
-      * decode:       ``cache=filled``, Sq=1 — appends one step.
+      * decode:       ``cache=filled``, Sq=1 — appends one step.  With
+                      ``layer``, ``cache`` is the stage's stack of caches and
+                      the step writes its one slot of that layer in place.
       * cross:        ``kv=(k,v)`` precomputed from context; no cache update.
     """
     B, Sq, D = x.shape
@@ -197,7 +208,7 @@ def attn_apply(
         return linear_apply(p["wo"], out.reshape(B, Sq, H * hd)), None
 
     # ------------------------------------------------- prefill / decode step
-    W = cache["k"].shape[2]
+    W = cache["pos"].shape[-1]
     if Sq > 1:
         # prefill: attend over the in-flight full sequence (correct even when
         # Sq > W), then persist only the last W entries into the ring.
@@ -211,11 +222,19 @@ def attn_apply(
         new_cache = _ring_write(cache, kc, vc, q_pos)
         return linear_apply(p["wo"], out.reshape(B, Sq, H * hd)), new_cache
 
-    # decode: append one step into the ring, attend over the cache
-    new_cache = _ring_write(cache, kc, vc, q_pos)
+    # decode: attend over the ring with the step's entry in its slot, then
+    # write the entry.  The attention reads the cache as it stood, with the
+    # entry selected in as it is read, so XLA keeps one layout for a stack
+    # carried through the layer scan (``layer``) and updates it in place.
+    own = cache if layer is None else jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False), cache)
+    slot = jnp.arange(W) == jnp.mod(q_pos[0], W)
+    k_att = jnp.where(slot[:, None], kc.astype(own["k"].dtype), own["k"])
+    v_att = jnp.where(slot[:, None], vc.astype(own["v"].dtype), own["v"])
     if exec_cfg.attn_impl == "flashcost":
-        out = _sdpa_flashcost(q, new_cache["k"], new_cache["v"])
+        out = _sdpa_flashcost(q, k_att, v_att)
     else:
-        mask = _mask(q_pos, new_cache["pos"], causal=causal, window=window)
-        out = _sdpa(q, new_cache["k"], new_cache["v"], mask)
+        mask = _mask(q_pos, jnp.where(slot, q_pos[0], own["pos"]), causal=causal, window=window)
+        out = _sdpa(q, k_att, v_att, mask)
+    new_cache = _ring_write(cache, kc, vc, q_pos, layer)
     return linear_apply(p["wo"], out.reshape(B, Sq, H * hd)), new_cache

@@ -8,6 +8,10 @@ functions share the signature::
 
 ``st`` is the block's cache/state ({} for stateless train-mode attention);
 ``ctx`` is the cross-attention context embeddings (B, N, D) when present.
+In decode on a carried stack (``layer`` given), ``st`` is the stage's
+stacked state and the block returns that stack with what the step changed
+written in place at ``layer``: one cache slot for attention, nothing for the
+cross-attention context, the whole (small) state for recurrent kinds.
 """
 from __future__ import annotations
 
@@ -111,9 +115,16 @@ def apply_block(
     mode: str,
     causal: bool,
     exec_cfg: ExecConfig,
+    layer=None,
 ) -> Tuple[jnp.ndarray, dict, jnp.ndarray]:
     zero = jnp.zeros((), jnp.float32)
     st = st or {}
+    if layer is not None and kind in ("rwkv", "rglru"):
+        own = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False), st)
+        x, new, aux = apply_block(kind, cfg, p, x, own, q_pos=q_pos, ctx=ctx, mode=mode,
+                                  causal=causal, exec_cfg=exec_cfg)
+        return x, jax.tree.map(
+            lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, layer, 0), st, new), aux
     if kind in ("attn", "cross"):
         h, new_kv = attn_apply(
             cfg,
@@ -124,14 +135,20 @@ def apply_block(
             causal=causal,
             window=cfg.window,
             exec_cfg=exec_cfg,
+            layer=layer,
         )
         x = x + h
         new_st = {"kv": new_kv} if new_kv is not None else {}
         if kind == "cross":
-            if mode == "decode":
-                xkv = (st["xk"], st["xv"])
+            if mode == "decode":  # the context's K/V never change in decode
+                new_st["xk"], new_st["xv"] = st["xk"], st["xv"]
+                xkv = (st["xk"], st["xv"]) if layer is None else tuple(
+                    jax.lax.dynamic_index_in_dim(a, layer, keepdims=False)
+                    for a in (st["xk"], st["xv"]))
             else:
                 xkv = cross_kv(cfg, p["xattn"], ctx)
+                if mode != "train":
+                    new_st["xk"], new_st["xv"] = xkv
             h, _ = attn_apply(
                 cfg,
                 p["xattn"],
@@ -143,8 +160,6 @@ def apply_block(
                 exec_cfg=exec_cfg,
             )
             x = x + h
-            if mode != "train":
-                new_st["xk"], new_st["xv"] = xkv
         h, aux = _apply_ffn(cfg, p["ffn"], norm_apply(cfg.norm, p["norm2"], x))
         return x + h, new_st, aux
 

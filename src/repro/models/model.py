@@ -93,21 +93,59 @@ class Model:
         ctx,
         mode,
         causal,
+        in_place=False,
     ):
+        """Run every stage's scan over its groups.  Each stage's state goes in
+        as the scan's ``xs`` and comes out freshly written as its ``ys``;
+        with ``in_place`` (decode only) the stacked state rides in the carry
+        instead and each layer writes only what its step changed."""
         ec = self.exec_cfg
         aux = jnp.zeros((), jnp.float32)
         new_states = []
         for si, (kinds, ng) in enumerate(stage_defs):
-            body = self.make_stage_body(kinds, q_pos=q_pos, ctx=ctx, mode=mode, causal=causal)
+            unroll = max(1, min(ec.scan_unroll, ng))
             with jax.named_scope("layers"):
-                (x, aux), st_out = jax.lax.scan(
-                    body,
-                    (x, aux),
-                    (stages_params[si], states[si]),
-                    unroll=max(1, min(ec.scan_unroll, ng)),
-                )
+                if in_place:
+                    (x, aux, st_out), _ = jax.lax.scan(
+                        self._in_place_body(kinds, q_pos=q_pos),
+                        (x, aux, states[si]),
+                        (stages_params[si], jnp.arange(ng, dtype=jnp.int32)),
+                        unroll=unroll,
+                    )
+                else:
+                    body = self.make_stage_body(
+                        kinds, q_pos=q_pos, ctx=ctx, mode=mode, causal=causal)
+                    (x, aux), st_out = jax.lax.scan(
+                        body, (x, aux), (stages_params[si], states[si]), unroll=unroll)
             new_states.append(st_out)
         return x, new_states, aux
+
+    def _in_place_body(self, kinds, *, q_pos):
+        """Decode scan body over a carried state: carry (x, aux, stage
+        state); xs (group_params, group index)."""
+
+        def body(carry, xs):
+            xc, auxc, st = carry
+            gp, layer = xs
+            st = dict(st)
+            for i, kind in enumerate(kinds):
+                xc, st[f"pos{i}"], a = apply_block(
+                    kind,
+                    self.cfg,
+                    gp[f"pos{i}"],
+                    xc,
+                    st[f"pos{i}"],
+                    q_pos=q_pos,
+                    ctx=None,
+                    mode="decode",
+                    causal=True,
+                    exec_cfg=self.exec_cfg,
+                    layer=layer,
+                )
+                auxc = auxc + a
+            return (xc, auxc, st), None
+
+        return body
 
     def make_stage_body(self, kinds, *, q_pos, ctx, mode, causal):
         """The per-group scan body: carry (x, aux); xs (group_params, group_state).
@@ -247,9 +285,16 @@ class Model:
         x = norm_apply(self.cfg.norm, params["final_norm"], x[:, -1:])
         return x[:, 0], states
 
-    def decode_step(self, params, token, states, pos) -> Tuple[jnp.ndarray, list]:
+    def decode_step(self, params, token, states, pos, *, in_place=False) -> Tuple[jnp.ndarray, list]:
         """One token for every sequence in the batch.  token: (B,1) int32;
-        pos: () int32 current absolute position.  Returns (logits (B,V), states)."""
+        pos: () int32 current absolute position.  Returns (logits (B,V), states).
+
+        Without ``in_place`` the layer scan reads ``states`` and writes the
+        returned states whole, into a fresh buffer.  With it the states ride
+        in the scan's carry and each layer writes only what its step changed
+        (one cache slot), in place.  A program may not write its own input,
+        so on states it was given XLA first copies them whole: that pays in
+        a loop of steps, not for one."""
         x = self.embed(params, token)
         q_pos = pos[None] if jnp.ndim(pos) == 0 else pos
         x, states, _ = self._run_stack(
@@ -261,6 +306,7 @@ class Model:
             ctx=None,
             mode="decode",
             causal=True,
+            in_place=in_place,
         )
         x = norm_apply(self.cfg.norm, params["final_norm"], x)
         return self.logits(params, x)[:, 0], states

@@ -6,8 +6,10 @@ its registered default tiles and with the smallest tile of its search space.
 The topology is described only inside the fixture, never while a module is
 imported: a process that describes it holds the TPU library until it exits.
 """
+import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -70,3 +72,70 @@ def test_kernel_compiles_for_v5e(one_chip, name, tiles):
     fn = functools.partial(spec.fn, **knobs, interpret=False)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), f"{name} {knobs}: no Pallas kernel"
+
+
+#: the decode cell's shapes: Qwen2-7B at published widths and bf16 weights,
+#: cut to a few layers so the compiles stay quick (but not so few that the
+#: stacked cache fits in on-chip memory); batch 32, cache 1536
+DECODE_LAYERS, DECODE_B, DECODE_W = 4, 32, 1536
+
+
+def _op_lines(text, shape):
+    """(op, result type) of every instruction whose result holds ``shape``,
+    outside the bodies of fusions (whose ops run inside the fusion)."""
+    fused = set(re.findall(r"calls=%([\w.-]+)", text))
+    for comp in re.split(r"\n(?=\S)", text):
+        name = re.match(r"(?:ENTRY )?%?([\w.-]*)", comp).group(1)
+        if name in fused:
+            continue
+        for line in comp.splitlines()[1:]:
+            m = re.match(r"\s*(?:ROOT )?%\S+ = (.+?) ([\w-]+)\(", line)
+            if m and shape in m.group(1):
+                yield m.group(2), m.group(1)
+
+
+@pytest.fixture(scope="module")
+def decode_programs(one_chip):
+    from repro import configs
+    from repro.launch import serve
+    from repro.models import ExecConfig, Model
+
+    cfg = dataclasses.replace(configs.get("qwen2_7b", n_layers=DECODE_LAYERS),
+                              param_dtype="bfloat16")
+    model = Model(cfg, ExecConfig(rec_chunk=4))
+
+    def spec(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+                            tree)
+
+    params = spec(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    states = spec(jax.eval_shape(lambda: model.init_states(DECODE_B, DECODE_W, mode="decode")))
+    token = jax.ShapeDtypeStruct((DECODE_B, 1), I32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), I32, sharding=one_chip)
+    k_cache = states[0]["pos0"]["kv"]["k"]
+    shape = "bf16[" + ",".join(map(str, k_cache.shape)) + "]"
+    cache_bytes = 2 * k_cache.size * k_cache.dtype.itemsize  # K and V
+    progs = {k: serve.decode_chunk(model, k).lower(params, token, states, pos).compile()
+             for k in (1, 8)}
+    return progs, shape, cache_bytes
+
+
+def test_decode_chunk_writes_the_cache_once_for_v5e(decode_programs):
+    """A chunk writes the stacked KV cache whole once: k=1 by its step's
+    write-back (no copy), k=8 by the copy XLA makes of its input at entry,
+    its steps then updating that copy in place, one slot a layer, with no
+    copy of the cache inside a loop."""
+    progs, shape, cache_bytes = decode_programs
+    text = {k: ex.as_text() for k, ex in progs.items()}
+    assert not [op for op, _ in _op_lines(text[1], shape) if op.startswith("copy")]
+    writes = [t for op, t in _op_lines(text[1], shape)
+              if op in ("fusion", "dynamic-update-slice") and "S(1)" not in t]
+    assert len(writes) <= 2, writes  # the K and V write-back of the one step
+    entry = text[8][text[8].index("\nENTRY "):]
+    copies = [op for op, _ in _op_lines(text[8], shape) if op.startswith("copy")]
+    assert copies == [op for op, _ in _op_lines(entry, shape) if op.startswith("copy")]
+    assert len(copies) <= 2, copies  # K and V, at entry only
+    # steps that wrote the cache out of place inside the loop held two more
+    # copies of it as temporaries (1.87 GB at 14 layers); what stays is a
+    # relayout of attention weights
+    assert progs[8].memory_analysis().temp_size_in_bytes < cache_bytes / 2
