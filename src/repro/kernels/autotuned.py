@@ -164,13 +164,13 @@ register(
         # q: (B,Sq,H,hd); k/v: (B,Kh,Skv,hd)
         space=lambda q, k, v, **kw: SearchSpace(
             [
-                _log_dim("block_q", q.shape[1], 16, 512),
-                _log_dim("block_kv", k.shape[2], 16, 512),
+                _log_dim("block_q", q.shape[1], 16, 1024),
+                _log_dim("block_kv", k.shape[2], 16, 1024),
             ]
         ),
         defaults=lambda q, k, v, **kw: {
-            "block_q": min(128, _pow2_floor(q.shape[1])),
-            "block_kv": min(128, _pow2_floor(k.shape[2])),
+            "block_q": min(1024, _pow2_floor(q.shape[1])),
+            "block_kv": min(1024, _pow2_floor(k.shape[2])),
         },
     )
 )

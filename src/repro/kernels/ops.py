@@ -8,10 +8,12 @@ correct on day one while the forward hot path uses the tuned kernels.
 
 All wrappers accept ``interpret=True`` so the kernel *bodies* execute on the
 CPU backend for validation; :func:`interpret_mode` is that choice made from
-the platform.
+the platform, and :func:`target_platform` the platform itself.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 
 import jax
@@ -23,24 +25,43 @@ from .flash_attention import flash_attention_fwd
 from .linear_scan import lru_scan_chunked, rwkv_scan_chunked
 from .matmul import matmul_tiled
 
-__all__ = ["flash_attention", "decode_attention", "rwkv_scan", "lru_scan", "matmul", "interpret_mode"]
+__all__ = [
+    "flash_attention", "decode_attention", "rwkv_scan", "lru_scan", "matmul",
+    "interpret_mode", "target_platform", "targeting",
+]
+
+_TARGET: contextvars.ContextVar = contextvars.ContextVar("kernel_target", default=None)
+
+
+def target_platform() -> str:
+    """The platform that programs traced now are built for: the default
+    backend, or the one a :func:`targeting` block names."""
+    return _TARGET.get() or jax.default_backend()
+
+
+@contextlib.contextmanager
+def targeting(platform: str):
+    """Trace for ``platform`` (e.g. ``"tpu"``) rather than the default
+    backend: a program compiled for a described chip from a host without one."""
+    token = _TARGET.set(platform)
+    try:
+        yield
+    finally:
+        _TARGET.reset(token)
 
 
 def interpret_mode() -> bool:
-    """Whether kernels run in Pallas interpret mode: only on the CPU backend,
+    """Whether kernels run in Pallas interpret mode: only on the CPU,
     which cannot run them compiled.  Every other platform runs them compiled."""
-    return jax.default_backend() == "cpu"
+    return target_platform() == "cpu"
 
 
 # ---------------------------------------------------------- flash attention
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q, k, v, causal, block_q, block_kv, interpret):
-    # q: (B,Sq,H,hd) layout; kernel wants (B,H,Sq,hd)
-    qt = q.transpose(0, 2, 1, 3)
-    o = flash_attention_fwd(
-        qt, k, v, causal=causal, block_q=block_q, block_kv=block_kv, interpret=interpret
+    return flash_attention_fwd(
+        q, k, v, causal=causal, block_q=block_q, block_kv=block_kv, interpret=interpret
     )
-    return o.transpose(0, 2, 1, 3)
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_kv, interpret):
@@ -56,7 +77,7 @@ def _flash_bwd(causal, block_q, block_kv, interpret, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_attention(q, k, v, *, causal=True, block_q=128, block_kv=128, interpret=False):
+def flash_attention(q, k, v, *, causal=True, block_q=1024, block_kv=1024, interpret=False):
     """q: (B,Sq,H,hd); k/v: (B,Kh,Skv,hd) -> (B,Sq,H,hd)."""
     return _flash(q, k, v, causal, block_q, block_kv, interpret)
 

@@ -16,6 +16,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+from repro.parallel.api import current_rules
+
 from .config import ExecConfig, ModelConfig
 from .layers import linear_apply, linear_init, rope_apply
 
@@ -93,9 +96,10 @@ def _sdpa(q, k, v, mask) -> jnp.ndarray:
     # NOTE (§Perf iteration 1, REFUTED hypothesis): explicit sharding
     # constraints on the S² chain were tried here and changed nothing — A/B
     # showed GSPMD already shards scores over (dp × heads); the term is big
-    # because S² itself is big.  The real fix is the flash kernel
-    # (kernels/flash_attention.py); see "flashcost" below for how the
-    # dry-run accounts for it.
+    # because S² itself is big.  The fix is the flash kernel
+    # (kernels/flash_attention.py), which cached prefill takes on a TPU
+    # (_prefill_takes_flash); see "flashcost" below for how the dry-run
+    # accounts for it.
     B, Sq, H, hd = q.shape
     Kh = k.shape[1]
     g = H // Kh
@@ -129,7 +133,9 @@ def _sdpa_flashcost(q, k, v) -> jnp.ndarray:
 
 
 def _sdpa_flash(q, k, v, *, causal: bool, exec_cfg: ExecConfig) -> jnp.ndarray:
-    """Pallas flash-attention path (train/prefill, no cache, full positions)."""
+    """Pallas flash-attention path: self-attention over full positions, query
+    and key i at position i (train/encode with ``attn_impl="pallas"``, and
+    every cached prefill that :func:`_prefill_takes_flash` sends here)."""
     from repro.kernels import ops as kops
 
     return kops.flash_attention(
@@ -141,6 +147,22 @@ def _sdpa_flash(q, k, v, *, causal: bool, exec_cfg: ExecConfig) -> jnp.ndarray:
         block_kv=exec_cfg.block_kv,
         interpret=exec_cfg.interpret,
     )
+
+
+def _prefill_takes_flash(causal: bool, window: int, exec_cfg: ExecConfig) -> bool:
+    """Whether a cached prefill runs the flash kernel: causal self-attention
+    over the whole prompt (no window) built for a TPU
+    (``kernels.ops.target_platform``), or in interpret mode (the CPU tests).  The S x S scores then stay on chip.  Everything else keeps
+    ``_sdpa``, a program sharded over several devices too: the partitioner
+    cannot split a Pallas call."""
+    if not causal or window > 0 or exec_cfg.attn_impl == "flashcost":
+        return False
+    ctx = current_rules()
+    if ctx is not None and ctx[0].size > 1:
+        return False
+    from repro.kernels import ops as kops
+
+    return exec_cfg.interpret or kops.target_platform() == "tpu"
 
 
 def cross_kv(cfg: ModelConfig, p: dict, ctx: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -211,8 +233,13 @@ def attn_apply(
     W = cache["pos"].shape[-1]
     if Sq > 1:
         # prefill: attend over the in-flight full sequence (correct even when
-        # Sq > W), then persist only the last W entries into the ring.
-        if exec_cfg.attn_impl == "flashcost":
+        # Sq > W), then persist only the last W entries into the ring.  The
+        # counters count lowerings (a layer scan traces its body once).
+        flash = _prefill_takes_flash(causal, window, exec_cfg)
+        obs.counter("attn.prefill_lowered." + ("flash" if flash else "xla")).inc()
+        if flash:
+            out = _sdpa_flash(q, kc, vc, causal=True, exec_cfg=exec_cfg)
+        elif exec_cfg.attn_impl == "flashcost":
             out = _sdpa_flashcost(q, kc, vc)
         else:
             mask = _mask(q_pos, q_pos, causal=causal, window=window)

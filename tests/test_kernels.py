@@ -7,7 +7,7 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.kernels import ops, ref
+from repro.kernels import flash_attention, ops, ref
 
 TOL = {jnp.float32: 2e-4, jnp.bfloat16: 2e-2}
 
@@ -26,6 +26,10 @@ def _tol(dt):
         (2, 4, 2, 64, 32, 32, 16),  # GQA g=2
         (1, 8, 1, 64, 16, 16, 64),  # MQA
         (1, 2, 1, 128, 64, 64, 32),
+        (1, 14, 2, 64, 32, 16, 32),  # GQA g=7, the grouping of Qwen2-7B's 28/4
+        (2, 14, 2, 50, 32, 16, 16),  # length not a tile multiple, B > 1
+        (2, 4, 2, 100, 16, 32, 64),  # padded in both tiles, bkv > bq
+        (2, 6, 2, 72, 128, 32, 16),  # heads one lane tile (128) wide
     ],
 )
 def test_flash_attention_sweep(dtype, causal, B, H, Kh, Sq, hd, bq, bkv):
@@ -57,6 +61,25 @@ def test_flash_attention_property(logsq, bq, bkv, seed):
     o = ops.flash_attention(q, k, v, causal=True, block_q=bq, block_kv=bkv, interpret=True)
     want = ref.flash_attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(o), np.asarray(want), atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("bq,bkv", [(16, 16), (16, 64), (64, 16), (32, 32)])
+def test_flash_attention_skips_future_tiles(bq, bkv):
+    """Under causal attention a KV tile entirely in a query tile's future
+    repeats the block index of the step before it, so the pipeline fetches
+    nothing new for it; every tile the query tile needs is visited in order."""
+    n_q, n_kv = 128 // bq, 128 // bkv
+    for iq in range(n_q):
+        idx = [int(flash_attention.kv_block_index(iq, ikv, block_q=bq, block_kv=bkv,
+                                                  causal=True)) for ikv in range(n_kv)]
+        last = ((iq + 1) * bq - 1) // bkv
+        assert idx[: last + 1] == list(range(last + 1))
+        for ikv in range(last + 1, n_kv):  # fully-future tiles
+            assert ikv * bkv > (iq + 1) * bq - 1
+            assert idx[ikv] == idx[ikv - 1]
+        assert [int(flash_attention.kv_block_index(iq, ikv, block_q=bq, block_kv=bkv,
+                                                   causal=False))
+                for ikv in range(n_kv)] == list(range(n_kv))
 
 
 def test_flash_attention_grad_matches_ref():

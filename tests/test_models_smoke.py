@@ -2,6 +2,8 @@
 train step, shape and finiteness assertions; decode == full-forward exactness;
 window ring-buffer correctness; MoE/aux behaviours.  Full configs are only
 exercised via the dry-run (ShapeDtypeStruct, no allocation)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -187,3 +189,76 @@ def test_depth_cut_keeps_published_widths():
     for f in ("d_model", "n_heads", "n_kv_heads", "d_head", "d_ff", "vocab_size", "group"):
         assert getattr(cut, f) == getattr(full, f), f
     assert configs.get("qwen2_7b") is full
+
+
+@pytest.mark.parametrize(
+    "S,max_len,window,heads",
+    [
+        (40, 48, 0, {}),  # the ring holds the prompt
+        (40, 24, 0, {}),  # a prompt longer than the ring (Sq > W)
+        (40, 48, 8, {}),  # local attention: keeps the XLA path in interpret mode too
+        (40, 48, 0, {"d_model": 256, "n_heads": 2, "n_kv_heads": 1, "d_head": 128}),  # 128-wide heads
+    ],
+)
+def test_prefill_flash_matches_xla(S, max_len, window, heads):
+    """A cached prefill through the flash kernel (interpret mode) gives the
+    XLA path's last hidden state, first token and cache; the lowering
+    counters say which path each took."""
+    from repro import obs
+
+    cfg = dataclasses.replace(configs.get_tiny("qwen2_7b"), window=window, **heads)
+    params = Model(cfg, EC).init(jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, S), 0, cfg.vocab_size)
+    out, lowered = {}, {}
+    for interpret in (False, True):
+        m = Model(cfg, dataclasses.replace(EC, interpret=interpret))
+
+        def prefill(params, tokens, m=m):
+            hidden, states = m.prefill(params, {"tokens": tokens, "max_len": max_len})
+            first = jnp.argmax(m.logits(params, hidden[:, None])[:, 0], -1)
+            return hidden, first, states
+
+        before = {p: obs.counter(f"attn.prefill_lowered.{p}").value for p in ("flash", "xla")}
+        out[interpret] = jax.jit(prefill)(params, tokens)
+        lowered[interpret] = {p: obs.counter(f"attn.prefill_lowered.{p}").value - n
+                              for p, n in before.items()}
+    assert lowered[False] == {"flash": 0, "xla": 1}  # one scan body, traced once
+    assert lowered[True] == ({"flash": 1, "xla": 0} if window == 0 else lowered[False])
+    (h_x, tok_x, st_x), (h_f, tok_f, st_f) = out[False], out[True]
+    np.testing.assert_allclose(np.asarray(h_f, np.float32), np.asarray(h_x, np.float32),
+                               atol=2e-2, rtol=2e-2)
+    np.testing.assert_array_equal(np.asarray(tok_f), np.asarray(tok_x))
+    kv_x, kv_f = st_x[0]["pos0"]["kv"], st_f[0]["pos0"]["kv"]
+    assert kv_f["k"].shape[-2] == min(max_len, window or max_len)
+    np.testing.assert_array_equal(np.asarray(kv_f["pos"]), np.asarray(kv_x["pos"]))
+    for name in ("k", "v"):
+        np.testing.assert_allclose(np.asarray(kv_f[name], np.float32),
+                                   np.asarray(kv_x[name], np.float32), atol=2e-2, rtol=2e-2)
+
+
+def test_prefill_path_follows_platform_window_and_sharding():
+    """The kernel serves causal whole-prompt attention on a TPU (the default
+    backend, or one a ``targeting`` block names) or in interpret mode; a
+    window, the CPU backend and a program sharded over several devices keep
+    the XLA path."""
+    import types
+
+    from repro.models import attention
+    from repro.parallel.api import ShardingRules, sharding_context
+
+    from repro.kernels import ops as kops
+
+    takes = attention._prefill_takes_flash
+    assert takes(True, 0, ExecConfig(interpret=True))
+    assert not takes(True, 0, ExecConfig())  # the CPU backend, compiled
+    with kops.targeting("tpu"):  # a described chip, compiled from this host
+        assert takes(True, 0, ExecConfig()) and not kops.interpret_mode()
+        assert not takes(True, 8, ExecConfig())
+    assert kops.interpret_mode()
+    assert not takes(True, 8, ExecConfig(interpret=True))
+    assert not takes(False, 0, ExecConfig(interpret=True))
+    assert not takes(True, 0, ExecConfig(interpret=True, attn_impl="flashcost"))
+    with sharding_context(types.SimpleNamespace(size=4), ShardingRules()):
+        assert not takes(True, 0, ExecConfig(interpret=True))
+    with sharding_context(types.SimpleNamespace(size=1), ShardingRules()):
+        assert takes(True, 0, ExecConfig(interpret=True))

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import ops as kops
 from repro.kernels.autotuned import get_spec, registered
 
 BF, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -139,3 +140,49 @@ def test_decode_chunk_writes_the_cache_once_for_v5e(decode_programs):
     # copies of it as temporaries (1.87 GB at 14 layers); what stays is a
     # relayout of attention weights
     assert progs[8].memory_analysis().temp_size_in_bytes < cache_bytes / 2
+
+
+#: the prefill cell's longest prompt: batch 1, 4096 tokens into a cache of
+#: 4112, Qwen2-7B at published widths cut to one layer
+PREFILL_B, PREFILL_S, PREFILL_W = 1, 4096, 4112
+
+
+def test_prefill_attention_runs_the_flash_kernel_for_v5e(one_chip):
+    """The engine's prefill compiled for a v5e runs its attention as the
+    Pallas kernel (a custom call under scope ``attn``) and holds no S x S
+    float32 scores.  Its temporaries (1.91 GB on the XLA path) are then the
+    FFN's bf16 activation of S x d_ff (155 MB) and less than 64 MB besides."""
+    from repro import configs
+    from repro.models import ExecConfig, Model
+
+    cfg = dataclasses.replace(configs.get("qwen2_7b", n_layers=1), param_dtype="bfloat16")
+    model = Model(cfg, ExecConfig(rec_chunk=4))
+
+    def prefill_first_token(params, tokens):
+        hidden, states = model.prefill(params, {"tokens": tokens, "max_len": PREFILL_W})
+        logits = model.logits(params, hidden[:, None])[:, 0]
+        return jnp.argmax(logits, -1).astype(I32)[:, None], states
+
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((PREFILL_B, PREFILL_S), I32, sharding=one_chip)
+    with kops.targeting("tpu"):  # the described chip is not the default backend
+        compiled = jax.jit(prefill_first_token).lower(params, tokens).compile()
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert any(re.search(r'op_name="[^"]*\battn\b', line) for line in kernels), kernels
+    g = cfg.n_heads // cfg.n_kv_heads
+    scores = f"f32[{PREFILL_B},{cfg.n_kv_heads},{g},{PREFILL_S},{PREFILL_S}]"
+    assert scores not in text
+    ffn_act = PREFILL_B * PREFILL_S * cfg.d_ff * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < ffn_act + (64 << 20)
+
+
+def test_flash_attention_compiles_for_v5e_with_narrow_heads(one_chip):
+    """Heads narrower than a lane tile (SeamlessM4T's 16 heads of 64, no
+    grouping) compile too."""
+    q = jax.ShapeDtypeStruct((1, 2048, 16, 64), BF, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 16, 2048, 64), BF, sharding=one_chip)
+    spec = get_spec("flash_attention")
+    fn = functools.partial(spec.fn, **spec.defaults(q, kv, kv), interpret=False)
+    assert "tpu_custom_call" in jax.jit(fn).lower(q, kv, kv).compile().as_text()
