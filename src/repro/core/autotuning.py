@@ -215,12 +215,14 @@ class Autotuning:
 
     def ctx_name(self) -> str:
         """Stable label for this search in spans and the obs event stream
-        (the DB key's name + shapes when tuning a fingerprinted context)."""
+        (the DB key's name + shapes when tuning a fingerprinted context; the
+        name alone for a key without array arguments, such as a launch)."""
         if self.key is not None:
             try:
-                return f"{self.key.name}{self.key.shapes()}"
+                shapes = self.key.shapes()
             except Exception:
                 return str(getattr(self.key, "name", self.key))
+            return self.key.name if shapes is None else f"{self.key.name}{shapes}"
         return f"search@{id(self):x}"
 
     # ----------------------------------------------------------- properties

@@ -9,11 +9,11 @@
   rounds, optimizer resets, and pretune grid cells — never recompile.
   Wall-clock *measurement* stays strictly serial for timing fidelity; only
   compilation overlaps.
-* :class:`AnalyticCost` — beyond-paper: roofline terms derived from an XLA
-  ``lowered``/``compiled`` artifact.  This is what lets the *distributed
-  config* search run on a CPU-only container (§Perf hillclimb): the cost of a
-  candidate is its dominant roofline term on the target hardware, not a wall
-  clock on the host.
+* :func:`roofline_terms` — beyond-paper: roofline terms derived from an XLA
+  ``compiled`` artifact.  This is what lets the dry-run launch search
+  (``launch/spaces.py``) score a candidate on a CPU-only host: its cost is
+  its dominant roofline term on the target hardware, not a wall clock on
+  the host.
 
 Also home to :func:`collective_bytes` — the HLO-text parser used by the
 roofline analysis (sums operand bytes of all-gather / all-reduce /

@@ -1,9 +1,9 @@
 """Beyond-paper baseline optimizers sharing the NumericalOptimizer interface.
 
 The paper's interface (§2.2) is explicitly designed so "other optimization
-methods can be incorporated as a new class".  These two are used as controls
-in the benchmarks (exhaustive truth for small spaces; random-search baseline
-for the CSA-vs-NM comparisons).
+methods can be incorporated as a new class".  These two are the exhaustive
+sweep of a small space (``strategy="grid"``) and the random-search control
+that the strategy tests race against CSA and Nelder-Mead.
 
 Both have trivially perfect batch shapes: the whole remaining sweep is one
 ``ask()`` round (no point depends on another's cost), so a batched driver can

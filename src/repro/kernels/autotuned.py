@@ -369,12 +369,12 @@ def tune_call(
     best point's ``cost_std``/``repeats_spent`` measurement confidence.
 
     ``cost_fn(executable, *args) -> float`` overrides wall-clock timing
-    (used by tests/benchmarks for deterministic costs); under the adaptive
-    policy each call supplies one repetition, and the roofline prefilter is
-    disabled unless an explicit ``bound_fn(point, executable)`` provides
-    bounds in the cost function's own units.  ``measure_stats``, if given a
-    dict, receives the measurement engine's counters (reps spent, culls,
-    roofline prunes) when the search finishes.
+    (tests and ``pretune --cost analytic`` use it for deterministic costs);
+    under the adaptive policy each call supplies one repetition, and the
+    roofline prefilter is disabled unless an explicit ``bound_fn(point,
+    executable)`` provides bounds in the cost function's own units.
+    ``measure_stats``, if given a dict, receives the measurement engine's
+    counters (reps spent, culls, roofline prunes) when the search finishes.
 
     ``strategy`` picks the search strategy (``"csa+nm"``, ``"csa|nm"``, ...
     — the :func:`repro.core.strategy.make_strategy` grammar) over the same

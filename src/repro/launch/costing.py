@@ -20,7 +20,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core.costs import collective_bytes, hlo_flops_bytes
 from repro.parallel.api import ShardingRules, logical_spec, sharding_context
@@ -159,67 +158,6 @@ def stage_probe(
         f = _measure(fwd_fn, fwd_out_sh)
         g = g + f
     return g
-
-
-def attention_traffic(cfg, shape, dp: int, tp: int) -> dict:
-    """Analytic per-chip HBM traffic of the attention score tensors.
-
-    Used by §Perf iterations that substitute the Pallas flash kernel for the
-    XLA attention path: the dry-run lowers XLA attention (Pallas cannot lower
-    without a TPU), so the kernel's effect on the memory term is applied as
-        bytes' = bytes - xla_scores + flash_io
-    with the estimates below (documented in EXPERIMENTS.md §Perf):
-
-      xla_scores: scores elems × 4 B × passes, passes = 6 (fwd) / 20 (train:
-                  fwd + remat recompute + bwd chains), ×0.5 if causal;
-      flash_io:   Q/K/V reads + O write only (the S² tile never leaves VMEM),
-                  ×1 (fwd) / ×3.5 (train).
-    Head sharding follows models.attention: KV heads if Kh % tp == 0, else
-    the GQA group dim if g % tp == 0, else batch-only.
-    """
-    train = shape.kind == "train"
-    B = shape.global_batch
-    S = shape.seq_len if shape.kind != "decode" else 1
-    Skv_decode = shape.seq_len
-    B_loc = B // dp if B % dp == 0 else B
-    H, Kh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
-    g = H // Kh
-    hshard = tp if (Kh % tp == 0 or g % tp == 0) else 1
-    passes = 20.0 if train else 6.0
-    fl_mult = 3.5 if train else 1.0
-    # MXU dot passes over the S² tile (QK + PV): fwd 2; train adds the remat
-    # recompute (2) and the backward chain dQ/dK/dV/dP (~5).
-    dot_passes = 9.0 if train else 2.0
-
-    def inst(sq, skv, causal):
-        frac = 0.5 if causal and sq == skv else 1.0
-        elems = B_loc * (H / hshard) * sq * skv * frac
-        xla = elems * 4.0 * passes
-        flash = B_loc * (2 * H * sq + 2 * Kh * skv) * hd * 2.0 * fl_mult
-        flops = dot_passes * elems * hd * 2.0
-        return xla, flash, flops
-
-    xla = flash = flops = 0.0
-    skv_self = min(cfg.window, S) if cfg.window else S
-    if shape.kind == "decode":
-        skv_self = min(cfg.window, Skv_decode) if cfg.window else Skv_decode
-    for kind in cfg.pattern:
-        if kind in ("attn", "cross"):
-            a, f, fl = inst(S, skv_self, causal=True)
-            xla += a
-            flash += f
-            flops += fl
-        if kind == "cross":
-            a, f, fl = inst(S, cfg.ctx_tokens, causal=False)
-            xla += a
-            flash += f
-            flops += fl
-    if cfg.is_encdec and shape.kind != "decode":
-        a, f, fl = inst(cfg.ctx_tokens, cfg.ctx_tokens, causal=False)
-        xla += a * cfg.enc_layers
-        flash += f * cfg.enc_layers
-        flops += fl * cfg.enc_layers
-    return {"xla_bytes": xla, "flash_bytes": flash, "flash_flops": flops}
 
 
 def corrected_cost(model, step_cost: CostTerms, probes: dict) -> CostTerms:

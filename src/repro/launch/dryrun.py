@@ -66,10 +66,9 @@ def _batch_wanted(path, ndim):
 
 
 def default_exec(cfg, shape_kind: str, overrides: dict | None = None) -> ExecConfig:
-    """Baseline execution config (the paper-faithful starting point; §Perf
-    hillclimbs override fields via ``overrides``)."""
+    """Baseline execution config (the paper-faithful starting point;
+    ``--set`` overrides fields via ``overrides``)."""
     kw = dict(
-        attn_impl="xla",  # dry-run lowers the XLA path (Pallas is the TPU runtime path)
         scan_layers=True,
         scan_unroll=1,
         remat="full" if shape_kind == "train" else "none",
@@ -393,7 +392,7 @@ def main():
     for kv in args.set:
         k, v = kv.split("=")
         overrides[k] = (
-            v if k in ("attn_impl", "remat") else (v == "True" if v in ("True", "False") else int(v))
+            v if k == "remat" else (v == "True" if v in ("True", "False") else int(v))
         )
 
     cells = []

@@ -36,8 +36,7 @@ Two measurement modes:
   * ``mode="dryrun"`` — lower + compile each candidate on the host-platform
     mesh via ``launch.dryrun.run_cell`` (with the candidate's compiler
     options) and charge the compiled roofline bound.  Real, slow; behind
-    ``pretune --launch --cost runtime`` and ``benchmarks/launch_tuning.py
-    --full``.
+    ``pretune --launch --cost runtime``.
 """
 from __future__ import annotations
 
@@ -116,9 +115,8 @@ def _pow2s(lo: int, hi: int) -> list:
 def _tp_ok(cfg, tp: int) -> bool:
     """Can the model axis shard this config ``tp`` ways?
 
-    Attention shards heads (KV heads, or the GQA group dim — mirroring
-    models.attention / costing.attention_traffic); attention-free stacks
-    (RWKV, RGLRU) shard ``d_model`` directly, which every layer needs
+    Attention shards heads (KV heads, or the GQA group dim); attention-free
+    stacks (RWKV, RGLRU) shard ``d_model`` directly, which every layer needs
     divisible anyway."""
     if tp == 1:
         return True
@@ -532,9 +530,9 @@ def tune_launch(
 
 
 def launch_cases(smoke: bool = True) -> list:
-    """(arch, shape_name) launch-tuning grid.  Smoke: the three zoo configs
-    the benchmark reports; full: every arch on the train shape plus the two
-    serving shapes for the smoke archs."""
+    """(arch, shape_name) launch-tuning grid.  Smoke: three zoo configs (a
+    dense, a recurrent and an expert stack); full: every arch on the train
+    shape plus the two serving shapes for the smoke archs."""
     smoke_cases = [
         ("qwen2_7b", "train_4k"),
         ("recurrentgemma_2b", "train_4k"),

@@ -19,7 +19,7 @@ import numpy as np
 from repro import obs
 from repro.parallel.api import current_rules
 
-from .config import ExecConfig, ModelConfig
+from .config import ModelConfig
 from .layers import linear_apply, linear_init, rope_apply
 
 __all__ = ["attn_init", "attn_apply", "init_attn_cache", "cross_kv"]
@@ -98,8 +98,7 @@ def _sdpa(q, k, v, mask) -> jnp.ndarray:
     # showed GSPMD already shards scores over (dp × heads); the term is big
     # because S² itself is big.  The fix is the flash kernel
     # (kernels/flash_attention.py), which cached prefill takes on a TPU
-    # (_prefill_takes_flash); see "flashcost" below for how the dry-run
-    # accounts for it.
+    # (_prefill_takes_flash).
     B, Sq, H, hd = q.shape
     Kh = k.shape[1]
     g = H // Kh
@@ -112,57 +111,29 @@ def _sdpa(q, k, v, mask) -> jnp.ndarray:
     return out.reshape(B, Sq, H, hd)
 
 
-def _sdpa_flashcost(q, k, v) -> jnp.ndarray:
-    """Kernel-cost surrogate for dry-run lowering (attn_impl='flashcost').
-
-    Pallas cannot lower without a TPU, so §Perf candidates that run the flash
-    kernel lower THIS surrogate instead: it reads Q/K/V once and writes O
-    once — exactly the kernel's HBM traffic (the S² tile lives in VMEM) —
-    while the kernel's MXU FLOPs are re-added analytically
-    (costing.attention_traffic / flash_flops).  Not a numerics path: only
-    lowered for cost accounting.
-    """
-    B, Sq, H, hd = q.shape
-    Kh = k.shape[1]
-    mk = jnp.mean(k, axis=2)  # (B,Kh,hd): touches all of K
-    mv = jnp.mean(v, axis=2)
-    g = H // Kh
-    mk = jnp.repeat(mk, g, axis=1)[:, None]  # (B,1,H,hd)
-    mv = jnp.repeat(mv, g, axis=1)[:, None]
-    return q * mk + mv
-
-
-def _sdpa_flash(q, k, v, *, causal: bool, exec_cfg: ExecConfig) -> jnp.ndarray:
-    """Pallas flash-attention path: self-attention over full positions, query
-    and key i at position i (train/encode with ``attn_impl="pallas"``, and
-    every cached prefill that :func:`_prefill_takes_flash` sends here)."""
+def _sdpa_flash(q, k, v) -> jnp.ndarray:
+    """Causal self-attention over a whole prompt through the Pallas flash
+    kernel: query and key i at position i.  Interpret mode only where the
+    platform cannot run the kernel compiled (the CPU)."""
     from repro.kernels import ops as kops
 
-    return kops.flash_attention(
-        q,
-        k,
-        v,
-        causal=causal,
-        block_q=exec_cfg.block_q,
-        block_kv=exec_cfg.block_kv,
-        interpret=exec_cfg.interpret,
-    )
+    return kops.flash_attention(q, k, v, causal=True, interpret=kops.interpret_mode())
 
 
-def _prefill_takes_flash(causal: bool, window: int, exec_cfg: ExecConfig) -> bool:
+def _prefill_takes_flash(causal: bool, window: int) -> bool:
     """Whether a cached prefill runs the flash kernel: causal self-attention
-    over the whole prompt (no window) built for a TPU
-    (``kernels.ops.target_platform``), or in interpret mode (the CPU tests).  The S x S scores then stay on chip.  Everything else keeps
-    ``_sdpa``, a program sharded over several devices too: the partitioner
-    cannot split a Pallas call."""
-    if not causal or window > 0 or exec_cfg.attn_impl == "flashcost":
+    over the whole prompt (no window) in a program built for one TPU device
+    (``kernels.ops.target_platform``).  The S x S scores then stay on chip.
+    Everything else keeps ``_sdpa``, a program sharded over several devices
+    too: the partitioner cannot split a Pallas call."""
+    if not causal or window > 0:
         return False
     ctx = current_rules()
     if ctx is not None and ctx[0].size > 1:
         return False
     from repro.kernels import ops as kops
 
-    return exec_cfg.interpret or kops.target_platform() == "tpu"
+    return kops.target_platform() == "tpu"
 
 
 def cross_kv(cfg: ModelConfig, p: dict, ctx: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -185,7 +156,6 @@ def attn_apply(
     causal: bool = True,
     window: int = 0,
     rope: bool = True,
-    exec_cfg: ExecConfig = ExecConfig(),
     layer=None,
 ) -> Tuple[jnp.ndarray, Optional[dict]]:
     """Self- or cross-attention with optional KV cache.
@@ -204,11 +174,7 @@ def attn_apply(
 
     if kv is not None:  # ---------------------------------------- cross-attn
         k, v = kv
-        if exec_cfg.attn_impl == "flashcost":
-            out = _sdpa_flashcost(q, k, v)
-        else:
-            mask = jnp.ones((Sq, k.shape[2]), bool)
-            out = _sdpa(q, k, v, mask)
+        out = _sdpa(q, k, v, jnp.ones((Sq, k.shape[2]), bool))
         return linear_apply(p["wo"], out.reshape(B, Sq, H * hd)), cache
 
     kc = linear_apply(p["wk"], x).reshape(B, Sq, Kh, hd)
@@ -220,13 +186,7 @@ def attn_apply(
     vc = vc.transpose(0, 2, 1, 3)
 
     if cache is None:  # ------------------------------------- train / encode
-        if exec_cfg.attn_impl == "pallas" and window == 0:
-            out = _sdpa_flash(q, kc, vc, causal=causal, exec_cfg=exec_cfg)
-        elif exec_cfg.attn_impl == "flashcost":
-            out = _sdpa_flashcost(q, kc, vc)
-        else:
-            mask = _mask(q_pos, q_pos, causal=causal, window=window)
-            out = _sdpa(q, kc, vc, mask)
+        out = _sdpa(q, kc, vc, _mask(q_pos, q_pos, causal=causal, window=window))
         return linear_apply(p["wo"], out.reshape(B, Sq, H * hd)), None
 
     # ------------------------------------------------- prefill / decode step
@@ -235,15 +195,12 @@ def attn_apply(
         # prefill: attend over the in-flight full sequence (correct even when
         # Sq > W), then persist only the last W entries into the ring.  The
         # counters count lowerings (a layer scan traces its body once).
-        flash = _prefill_takes_flash(causal, window, exec_cfg)
+        flash = _prefill_takes_flash(causal, window)
         obs.counter("attn.prefill_lowered." + ("flash" if flash else "xla")).inc()
         if flash:
-            out = _sdpa_flash(q, kc, vc, causal=True, exec_cfg=exec_cfg)
-        elif exec_cfg.attn_impl == "flashcost":
-            out = _sdpa_flashcost(q, kc, vc)
+            out = _sdpa_flash(q, kc, vc)
         else:
-            mask = _mask(q_pos, q_pos, causal=causal, window=window)
-            out = _sdpa(q, kc, vc, mask)
+            out = _sdpa(q, kc, vc, _mask(q_pos, q_pos, causal=causal, window=window))
         if Sq > W:
             kc, vc, q_pos = kc[:, :, Sq - W :], vc[:, :, Sq - W :], q_pos[Sq - W :]
         new_cache = _ring_write(cache, kc, vc, q_pos)
@@ -258,10 +215,7 @@ def attn_apply(
     slot = jnp.arange(W) == jnp.mod(q_pos[0], W)
     k_att = jnp.where(slot[:, None], kc.astype(own["k"].dtype), own["k"])
     v_att = jnp.where(slot[:, None], vc.astype(own["v"].dtype), own["v"])
-    if exec_cfg.attn_impl == "flashcost":
-        out = _sdpa_flashcost(q, k_att, v_att)
-    else:
-        mask = _mask(q_pos, jnp.where(slot, q_pos[0], own["pos"]), causal=causal, window=window)
-        out = _sdpa(q, k_att, v_att, mask)
+    mask = _mask(q_pos, jnp.where(slot, q_pos[0], own["pos"]), causal=causal, window=window)
+    out = _sdpa(q, k_att, v_att, mask)
     new_cache = _ring_write(cache, kc, vc, q_pos, layer)
     return linear_apply(p["wo"], out.reshape(B, Sq, H * hd)), new_cache

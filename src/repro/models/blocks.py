@@ -134,7 +134,6 @@ def apply_block(
             cache=st.get("kv"),
             causal=causal,
             window=cfg.window,
-            exec_cfg=exec_cfg,
             layer=layer,
         )
         x = x + h
@@ -157,7 +156,6 @@ def apply_block(
                 kv=xkv,
                 causal=False,
                 rope=False,
-                exec_cfg=exec_cfg,
             )
             x = x + h
         h, aux = _apply_ffn(cfg, p["ffn"], norm_apply(cfg.norm, p["norm2"], x))
@@ -168,9 +166,7 @@ def apply_block(
         return y, new_st, zero
 
     if kind == "rglru":
-        h, new_st = rglru_apply(
-            cfg, p["rec"], norm_apply(cfg.norm, p["norm1"], x), st, exec_cfg=exec_cfg
-        )
+        h, new_st = rglru_apply(cfg, p["rec"], norm_apply(cfg.norm, p["norm1"], x), st)
         x = x + h
         h = ffn_apply(
             cfg.ffn if cfg.ffn != "moe" else "geglu",

@@ -154,17 +154,13 @@ class ModelConfig:
 
 @dataclasses.dataclass(frozen=True)
 class ExecConfig:
-    """Execution-time knobs (most are PATSMA-tunable; model-agnostic)."""
+    """Execution-time knobs (most are PATSMA-tunable; model-agnostic).  The
+    attention kernel is not among them: ``models.attention`` picks it from
+    the platform a program is built for."""
 
-    # xla | pallas | flashcost (dry-run surrogate): the uncached train/encode
-    # path's choice; a cached prefill takes the kernel by platform and shape
-    attn_impl: str = "xla"
     scan_layers: bool = True
     scan_unroll: int = 1
     remat: str = "none"  # none | full | dots  (activation checkpointing)
     logits_chunk: int = 0  # 0 = unchunked loss; else vocab-chunked CE
     rec_chunk: int = 128  # linear-recurrence chunk length (rwkv/rglru)
     rec_unroll: bool = False  # unroll the chunk loop (exact dry-run cost_analysis)
-    block_q: int = 1024  # pallas flash attention tiles (v5e sweep, PERF.md)
-    block_kv: int = 1024
-    interpret: bool = False  # pallas interpret mode (CPU tests)

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .config import ExecConfig, ModelConfig
+from .config import ModelConfig
 from .layers import _nrm
 
 __all__ = ["rglru_init", "rglru_apply", "init_rglru_state", "lru_scan_ref", "lru_scan"]
@@ -100,8 +100,6 @@ def rglru_apply(
     p: dict,
     x: jnp.ndarray,
     state: dict,
-    *,
-    exec_cfg: ExecConfig = ExecConfig(),
 ) -> Tuple[jnp.ndarray, dict]:
     """Temporal-mix half of the Griffin block (residual handled by caller).
     x: (B,T,D) pre-normed. Returns (out (B,T,D), new_state)."""
@@ -123,12 +121,6 @@ def rglru_apply(
     if x.shape[1] == 1:
         h = a[:, 0] * state["h"] + b[:, 0]
         hs, hT = h[:, None], h
-    elif exec_cfg.attn_impl == "pallas" and x.shape[1] % max(exec_cfg.rec_chunk, 1) == 0:
-        from repro.kernels import ops as kops
-
-        hs, hT = kops.lru_scan(
-            a, b, state["h"], chunk=exec_cfg.rec_chunk, interpret=exec_cfg.interpret
-        )
     else:
         hs, hT = lru_scan(a, b, state["h"])
 

@@ -192,13 +192,6 @@ def rwkv_apply(
 
     if T == 1 or exec_cfg.rec_chunk <= 1 or T % min(exec_cfg.rec_chunk, T):
         y, sT = wkv_scan_ref(r, k, v, lw, tm["u"], state["wkv"])
-    elif exec_cfg.attn_impl == "pallas":
-        from repro.kernels import ops as kops
-
-        y, sT = kops.rwkv_scan(
-            r, k, v, lw, tm["u"], state["wkv"],
-            chunk=exec_cfg.rec_chunk, interpret=exec_cfg.interpret,
-        )
     else:
         y, sT = wkv_chunked(
             r, k, v, lw, tm["u"], state["wkv"],

@@ -11,8 +11,7 @@ whole registry to one JSON-able dict for ``repro.tune report`` and the
 ``metrics.json`` artifact.
 
 Everything here is always-on: an increment is one lock acquisition on ints,
-cheap enough that no call site needs gating (the <2% disabled-overhead gate
-in ``benchmarks/obs_overhead.py`` measures exactly this).
+cheap enough that no call site needs gating.
 """
 from __future__ import annotations
 

@@ -33,7 +33,7 @@ _TLS = threading.local()
 class ShardingRules:
     dp: Axis = ("data",)
     tp: Axis = "model"
-    sp: Axis = None  # sequence-parallel activations (hillclimb knob)
+    sp: Axis = None  # sequence-parallel activations
     ep: Axis = "model"
     fsdp: Axis = ("data",)  # weight sharding axes (ZeRO-3); None disables
 

@@ -16,8 +16,8 @@ utilities trade precision for wire bytes on the DP all-reduce:
                           threaded through the step signature.
 
 The implicit-SPMD train path keeps XLA's native all-reduce; this module is
-the explicit path for bandwidth-starved cross-pod reductions (benchmarked in
-benchmarks/grad_compression.py, tested in tests/test_collectives.py).
+the explicit path for bandwidth-starved cross-pod reductions (tested in
+tests/test_parallel.py and tests/test_launch_spaces.py).
 """
 from __future__ import annotations
 

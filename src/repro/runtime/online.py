@@ -365,7 +365,7 @@ class OnlineTuner:
             def build():
                 if threading.get_ident() == serving_thread:
                     # only possible if a caller runs the future inline —
-                    # surfaced in stats so benchmarks can assert it never does
+                    # surfaced in stats so tests can assert it never does
                     with self._lock:
                         self.stats_["inband_builds"] += 1
                 return self._build(point, *args, **kwargs)
@@ -727,8 +727,8 @@ class OnlineTuner:
     # ------------------------------------------------------------- offline
     def drive(self, cost_fn: Callable[[dict], float], *args, **kwargs) -> dict:
         """Entire-Execution glue: run the search to completion now, with
-        ``cost_fn(point)`` supplying each candidate's cost (the launcher /
-        hillclimb loop).  Exploration is forced — ε only rations *live*
+        ``cost_fn(point)`` supplying each candidate's cost (an offline
+        search).  Exploration is forced — ε only rations *live*
         traffic, and here every call is a replica evaluation.  Offline there
         is no serving thread to protect, so a pending candidate build is
         simply waited for."""

@@ -207,32 +207,3 @@ def test_matmul_property_tile_invariance(bm, bn, bk, seed):
     b = jax.random.normal(ks[1], (64, 64))
     o = ops.matmul(a, b, bm=bm, bn=bn, bk=bk, interpret=True)
     np.testing.assert_allclose(np.asarray(o), np.asarray(ref.matmul_ref(a, b)), atol=1e-4)
-
-
-def test_model_uses_pallas_attention_path():
-    """End-to-end: a tiny model with attn_impl='pallas' matches the xla path."""
-    from repro import configs
-    from repro.models import ExecConfig, Model
-
-    cfg = configs.get_tiny("qwen2_7b")
-    mx = Model(cfg, ExecConfig(attn_impl="xla"))
-    mp = Model(cfg, ExecConfig(attn_impl="pallas", interpret=True, block_q=16, block_kv=16))
-    params = mx.init(jax.random.PRNGKey(0))
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, cfg.vocab_size)
-    hx, _ = mx.forward(params, {"tokens": tokens})
-    hp, _ = mp.forward(params, {"tokens": tokens})
-    np.testing.assert_allclose(np.asarray(hx, np.float32), np.asarray(hp, np.float32), atol=3e-2, rtol=3e-2)
-
-
-def test_model_uses_pallas_rwkv_path():
-    from repro import configs
-    from repro.models import ExecConfig, Model
-
-    cfg = configs.get_tiny("rwkv6_7b")
-    mx = Model(cfg, ExecConfig(rec_chunk=8))
-    mp = Model(cfg, ExecConfig(attn_impl="pallas", interpret=True, rec_chunk=8))
-    params = mx.init(jax.random.PRNGKey(0))
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, cfg.vocab_size)
-    hx, _ = mx.forward(params, {"tokens": tokens})
-    hp, _ = mp.forward(params, {"tokens": tokens})
-    np.testing.assert_allclose(np.asarray(hx, np.float32), np.asarray(hp, np.float32), atol=3e-2, rtol=3e-2)

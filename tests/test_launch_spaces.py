@@ -233,6 +233,48 @@ def test_launch_keys_roundtrip_db_cli(tmp_path, capsys):
     assert "shape=train_4k" in out and "None" not in out
 
 
+@pytest.mark.parametrize("arch,shape_name", [
+    ("qwen2_7b", "train_4k"), ("recurrentgemma_2b", "train_4k"),
+    ("moonshot_v1_16b_a3b", "train_4k"),
+])
+def test_tune_launch_smoke_case_beats_default_and_scores_only_valid_points(
+        tmp_path, arch, shape_name):
+    """Each smoke launch case commits a launch no slower than the untuned
+    default, its constraints prune part of the raw space, and the event
+    stream shows only valid points scored, only invalid points charged as
+    constraint prunes, and balanced candidate accounting."""
+    from repro import configs
+    from repro.launch.spaces import launch_cases, launch_space, tune_launch
+    from repro.obs import completeness, read_events
+    from repro.obs.events import EventSink, set_sink
+    from repro.tuning import TuningDB
+
+    assert (arch, shape_name) in launch_cases(smoke=True)
+    space = launch_space(configs.get(arch), configs.SHAPES[shape_name], 8)
+    epath = str(tmp_path / "events.jsonl")
+    sink = EventSink(epath)
+    set_sink(sink)
+    stats: dict = {}
+    try:
+        rec = tune_launch(arch, shape_name, 8, db=TuningDB(str(tmp_path / "l.json")),
+                          mode="model", num_opt=3, max_iter=4, seed=0,
+                          warm_start=False, stats=stats)
+    finally:
+        set_sink(None)
+        sink.close()
+    assert rec is not None
+    assert rec.cost <= stats["default_cost"] * (1 + 1e-9)
+    assert 0.0 < 1.0 - stats["constrained_size"] / stats["raw_size"] < 1.0
+    events = [e for e in read_events(epath) if e.get("name") == f"launch/{arch}"]
+    committed = [e for e in events if e.get("type") == "candidate_committed"]
+    pruned = [e for e in events if e.get("type") == "candidate_skipped"
+              and e.get("reason") == "constraint"]
+    assert len(committed) == stats["measured"] and len(pruned) == stats["pruned"] > 0
+    assert all(space.check(e["point"]) is None for e in committed)
+    assert all(space.check(e["point"]) is not None for e in pruned)
+    assert completeness(events)[f"launch/{arch}"]["balanced"]
+
+
 # ----------------------------------------------------- chunked collectives
 @pytest.mark.multidevice
 def test_chunked_psum_matches_dense():

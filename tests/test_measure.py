@@ -261,6 +261,53 @@ def test_measure_meta_survives_pipeline_stage_transition():
             assert meta["repeats_spent"] == 3
 
 
+def _bowl(point):
+    """True cost of a two-knob search: a smooth bowl at (t1=16, t2=64), 1 ms
+    at the optimum, with gaps between grid points far above the jitter."""
+    return 1e-3 * (1.0 + math.log2(point["t1"] / 16.0) ** 2 + math.log2(point["t2"] / 64.0) ** 2)
+
+
+def _race_bowl(policy, bounds):
+    """One seeded CSA search of the bowl through a MeasureEngine whose
+    repetitions return the true cost under a deterministic 0.1% jitter;
+    returns (driver, engine, repetitions spent)."""
+    space = SearchSpace([LogIntDim("t1", 4, 64), LogIntDim("t2", 16, 256)])
+    at = Autotuning(space=space, ignore=0, search=CSA(2, num_opt=5, max_iter=4, seed=0),
+                    cache=True)
+    engine = MeasureEngine(policy)
+    seen: dict = {}
+
+    def rep_fn(p):
+        def rep():
+            key = (p["t1"], p["t2"])
+            i = seen[key] = seen.get(key, -1) + 1
+            v = ((p["t1"] * 1009 + p["t2"]) * 2654435761 + i * 40503 + 12345) & 0xFFFFFFFF
+            return _bowl(p) * (1.0 + 1e-3 * ((v / 0xFFFFFFFF) * 2.0 - 1.0))
+
+        return rep
+
+    at.entire_exec_batch(lambda pts: engine.measure_round(
+        [rep_fn(p) for p in pts], bounds=[0.9 * _bowl(p) for p in pts] if bounds else None))
+    return at, engine, sum(seen.values()) + len(seen)
+
+
+def test_adaptive_racing_halves_reps_and_keeps_fixed_best():
+    """The same seeded search under adaptive racing (with a roofline bound
+    at 90% of the true cost) spends at most half the repetitions of the
+    fixed warmup-1 + 3-rep schedule, ends on the same best point, and never
+    culls a candidate within the noise floor of the winner."""
+    at_f, _, reps_fixed = _race_bowl(MeasurePolicy(mode="fixed", warmup=1, repeats=3), False)
+    at_a, eng, reps_adaptive = _race_bowl(MeasurePolicy(mode="adaptive", warmup=1), True)
+    assert reps_adaptive <= 0.5 * reps_fixed
+    assert at_a.best_point == at_f.best_point
+    assert eng.stats["culled"] > 0
+    floor = eng._noise().floor(_bowl(at_a.best_point))
+    for p, _ in at_a.history:
+        meta = at_a.measurement_meta(p)
+        if meta and meta.get("culled"):
+            assert _bowl(p) - _bowl(at_a.best_point) > floor, p
+
+
 def test_measurements_count_reps_actually_spent():
     space = SearchSpace([IntDim("k", 0, 3)])
     at = Autotuning(space=space, ignore=0,

@@ -196,22 +196,36 @@ def test_depth_cut_keeps_published_widths():
     [
         (40, 48, 0, {}),  # the ring holds the prompt
         (40, 24, 0, {}),  # a prompt longer than the ring (Sq > W)
-        (40, 48, 8, {}),  # local attention: keeps the XLA path in interpret mode too
+        (37, 24, 0, {}),  # an odd prompt length, longer than the ring
+        (40, 48, 8, {}),  # local attention: keeps the XLA path on a TPU too
         (40, 48, 0, {"d_model": 256, "n_heads": 2, "n_kv_heads": 1, "d_head": 128}),  # 128-wide heads
+        # the served configuration's grouping: 28 query heads over 4 KV heads (7:1)
+        (40, 48, 0, {"d_model": 448, "n_heads": 28, "n_kv_heads": 4, "d_head": 16}),
     ],
 )
-def test_prefill_flash_matches_xla(S, max_len, window, heads):
-    """A cached prefill through the flash kernel (interpret mode) gives the
-    XLA path's last hidden state, first token and cache; the lowering
-    counters say which path each took."""
+def test_prefill_flash_matches_xla(S, max_len, window, heads, monkeypatch):
+    """A cached prefill through the flash kernel (interpret mode, as a
+    program built for a TPU would take it) gives the XLA path's last hidden
+    state, first token and cache; the lowering counters say which path
+    each took."""
     from repro import obs
+    from repro.kernels import ops as kops
+    from repro.models import attention
+
+    takes = attention._prefill_takes_flash
+
+    def takes_on_tpu(causal, window):
+        with kops.targeting("tpu"):
+            return takes(causal, window)
 
     cfg = dataclasses.replace(configs.get_tiny("qwen2_7b"), window=window, **heads)
     params = Model(cfg, EC).init(jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(3), (2, S), 0, cfg.vocab_size)
     out, lowered = {}, {}
-    for interpret in (False, True):
-        m = Model(cfg, dataclasses.replace(EC, interpret=interpret))
+    for tpu in (False, True):
+        if tpu:
+            monkeypatch.setattr(attention, "_prefill_takes_flash", takes_on_tpu)
+        m = Model(cfg, EC)  # a fresh model and function: each loop traces anew
 
         def prefill(params, tokens, m=m):
             hidden, states = m.prefill(params, {"tokens": tokens, "max_len": max_len})
@@ -219,9 +233,9 @@ def test_prefill_flash_matches_xla(S, max_len, window, heads):
             return hidden, first, states
 
         before = {p: obs.counter(f"attn.prefill_lowered.{p}").value for p in ("flash", "xla")}
-        out[interpret] = jax.jit(prefill)(params, tokens)
-        lowered[interpret] = {p: obs.counter(f"attn.prefill_lowered.{p}").value - n
-                              for p, n in before.items()}
+        out[tpu] = jax.jit(prefill)(params, tokens)
+        lowered[tpu] = {p: obs.counter(f"attn.prefill_lowered.{p}").value - n
+                        for p, n in before.items()}
     assert lowered[False] == {"flash": 0, "xla": 1}  # one scan body, traced once
     assert lowered[True] == ({"flash": 1, "xla": 0} if window == 0 else lowered[False])
     (h_x, tok_x, st_x), (h_f, tok_f, st_f) = out[False], out[True]
@@ -237,28 +251,24 @@ def test_prefill_flash_matches_xla(S, max_len, window, heads):
 
 
 def test_prefill_path_follows_platform_window_and_sharding():
-    """The kernel serves causal whole-prompt attention on a TPU (the default
-    backend, or one a ``targeting`` block names) or in interpret mode; a
+    """The kernel serves causal whole-prompt attention in a program built
+    for a TPU (the default backend, or one a ``targeting`` block names); a
     window, the CPU backend and a program sharded over several devices keep
     the XLA path."""
     import types
 
+    from repro.kernels import ops as kops
     from repro.models import attention
     from repro.parallel.api import ShardingRules, sharding_context
 
-    from repro.kernels import ops as kops
-
     takes = attention._prefill_takes_flash
-    assert takes(True, 0, ExecConfig(interpret=True))
-    assert not takes(True, 0, ExecConfig())  # the CPU backend, compiled
-    with kops.targeting("tpu"):  # a described chip, compiled from this host
-        assert takes(True, 0, ExecConfig()) and not kops.interpret_mode()
-        assert not takes(True, 8, ExecConfig())
+    assert not takes(True, 0)  # the CPU backend
     assert kops.interpret_mode()
-    assert not takes(True, 8, ExecConfig(interpret=True))
-    assert not takes(False, 0, ExecConfig(interpret=True))
-    assert not takes(True, 0, ExecConfig(interpret=True, attn_impl="flashcost"))
-    with sharding_context(types.SimpleNamespace(size=4), ShardingRules()):
-        assert not takes(True, 0, ExecConfig(interpret=True))
-    with sharding_context(types.SimpleNamespace(size=1), ShardingRules()):
-        assert takes(True, 0, ExecConfig(interpret=True))
+    with kops.targeting("tpu"):  # a described chip, compiled from this host
+        assert takes(True, 0) and not kops.interpret_mode()
+        assert not takes(True, 8)
+        assert not takes(False, 0)
+        with sharding_context(types.SimpleNamespace(size=4), ShardingRules()):
+            assert not takes(True, 0)
+        with sharding_context(types.SimpleNamespace(size=1), ShardingRules()):
+            assert takes(True, 0)

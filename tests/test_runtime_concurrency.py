@@ -143,6 +143,48 @@ def test_rung_accounting_under_cross_stream_racing():
         assert s["explore_reps_decided"] <= 3 * s["explore_candidates"]
 
 
+def test_cross_stream_racing_matches_the_serial_search():
+    """Streams that race one context's rungs reach the serial search's best
+    point within its request count, give or take the one request per
+    stream already in flight when the search finishes."""
+    import time
+
+    policy = MeasurePolicy(mode="fixed", repeats=3)
+
+    def tuner():
+        router = ContextRouter()
+        router.register("race", space=lambda *a, **k: _space(16),
+                        defaults=lambda *a, **k: {"p": 4}, epsilon=1.0,
+                        num_opt=3, max_iter=3, measure=policy)
+        return router.tuner("race", extra={"shape": 0})
+
+    def cost(point):
+        return 1.0 + 0.05 * (point["p"] - 11) ** 2
+
+    serial = tuner()
+    serial_calls = 0
+    while not serial.finished:
+        d = serial.begin()
+        serial.observe(d, cost(d.point))
+        serial_calls += 1
+
+    racing = tuner()
+    calls = [0] * THREADS
+
+    def serve(i, r):
+        if racing.finished:
+            return
+        d = racing.begin(tenant=f"t{i}")
+        racing.observe(d, cost(d.point))
+        calls[i] += 1
+        time.sleep(1e-4)  # the request's serving work, so streams interleave
+
+    _hammer(serve, reps=serial_calls + 1)
+    assert racing.finished
+    assert racing.best_point == serial.best_point
+    assert sum(calls) <= serial_calls + THREADS
+
+
 # ------------------------------------------------------------------- builds
 def test_no_duplicate_inflight_builds_per_signature():
     """Racing threads asking for the same (point, signature) executable get

@@ -5,6 +5,7 @@ clock), the ε-scheduler is a credit counter (no RNG), and where background
 builds are involved the tests drain the pool between serving calls so
 readiness is reproducible.
 """
+import math
 import threading
 
 import numpy as np
@@ -481,6 +482,82 @@ def test_router_exact_hit_serves_stored_best_from_first_request():
     d = r2.begin("k", a)
     assert d.kind == EXPLOIT and d.point == best
     assert r2.tuner("k", a).finished
+
+
+def test_router_recovers_from_workload_shift_and_cost_drift():
+    """A served trace through one route in three phases: a first request
+    shape tuned from cold; a new shape, whose context warm-starts from the
+    first one's record; then a moved cost surface, which fires the drift
+    detector and a re-search.  At the end of each phase the deployed knobs
+    serve within 10% of the oracle, and no candidate is built on the
+    serving thread or built twice."""
+    # (request shape, optimal t, cost at the optimum, requests)
+    phases = [((64, 32), 32, 1.0, 140), ((256, 32), 128, 1.2, 170), ((256, 32), 256, 2.0, 170)]
+    router = ContextRouter(db=TuningDB(None), cache=ExecutableCache(), jobs=2)
+    router.register(
+        "sim_kernel",
+        space=lambda x: SearchSpace([LogIntDim("t", 8, 512)]),
+        defaults=lambda x: {"t": 64},
+        build=lambda point, *args: ("exe", point["t"]),
+        epsilon=0.35, num_opt=3, max_iter=3, seed=0,
+        drift={"window": 10, "min_samples": 5, "factor": 1.3},
+    )
+    warm = []
+    for shape, opt_t, base, n in phases:
+        x = np.zeros(shape, np.float32)
+
+        def cost(point):
+            return base + 0.25 * math.log2(point["t"] / opt_t) ** 2
+
+        deployed = []
+        for i in range(n):
+            d = router.begin("sim_kernel", x)
+            router.observe(d, cost(d.point))
+            router.wait_pending()  # candidate builds finish between requests
+            if i == 0:
+                warm.append(d.tuner.at.warm_started)
+            deployed.append(cost(d.tuner.exploit_point()))
+        assert np.mean(deployed[-10:]) <= 1.1 * base, (shape, opt_t)
+    assert warm[:2] == [False, True]
+    s = router.stats()
+    assert s["contexts"] == 2 and s["drift_resets"] >= 1
+    assert s["inband_builds"] == 0 and s["cache"]["recompiles"] == 0
+
+
+def test_p99_objective_picks_the_flat_tail_over_the_spiky_median():
+    """On a surface whose best-median points spike every fourth repetition,
+    a median search and a p99 search pick different winners, and the p99
+    winner's tail is no worse than the median winner's."""
+    from repro.core.measure import MeasurePolicy, quantile
+
+    def rep_cost(p, k):  # k: the point's repetition index
+        if p <= 4:
+            base = 1.0 + 0.03 * abs(p - 3)  # median optimum: p=3
+            return base * 4.0 if k % 4 == 3 else base
+        return 1.06 + 0.03 * abs(p - 6)  # flat; tail optimum: p=6
+
+    winners = {}
+    for objective in ("median", "p99"):
+        at = Autotuning(space=SearchSpace([IntDim("p", 1, 8)]), ignore=0,
+                        search=CSA(1, num_opt=3, max_iter=4, seed=0), cache=True,
+                        objective=objective)
+        tuner = OnlineTuner(at, epsilon=1.0,
+                            measure=MeasurePolicy(mode="fixed", repeats=16, objective=objective))
+        reps: dict = {}
+        for _ in range(20_000):
+            if tuner.finished:
+                break
+            d = tuner.begin()
+            if d.kind == EXPLORE:
+                k = reps[d.point["p"]] = reps.get(d.point["p"], -1) + 1
+                tuner.observe(d, rep_cost(d.point["p"], k))
+            else:
+                tuner.observe(d, 1.0)
+        assert tuner.finished
+        winners[objective] = tuner.best_point["p"]
+    tail = {o: quantile([rep_cost(p, k) for k in range(64)], 0.99) for o, p in winners.items()}
+    assert winners["median"] != winners["p99"]
+    assert tail["p99"] <= tail["median"]
 
 
 def test_router_rejects_unknown_route_and_detached_decision():

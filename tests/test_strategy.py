@@ -29,6 +29,20 @@ def sphere(z):
     return float(np.sum(np.asarray(z) ** 2))
 
 
+def rastrigin(z):
+    x = np.asarray(z) * 2.0
+    return float(10 * x.size + np.sum(x**2 - 10 * np.cos(2 * np.pi * x)))
+
+
+def rosenbrock(z):
+    x = np.asarray(z) * 2.0
+    return float(np.sum(100 * (x[1:] - x[:-1] ** 2) ** 2 + (1 - x[:-1]) ** 2))
+
+
+#: deterministic cost models on the unit box, each with its optimum at 0
+COST_MODELS = {"sphere": sphere, "rastrigin": rastrigin, "rosenbrock": rosenbrock}
+
+
 def drive(opt, fn):
     """Run a strategy to completion via ask/tell; returns total tells."""
     n = 0
@@ -395,6 +409,15 @@ def test_autotuning_strategy_spec_and_exclusivity():
         Autotuning(dim=1, strategy="csa", optimizer=CSA(1, num_opt=2, max_iter=2))
 
 
+@pytest.mark.parametrize("ignore", [0, 1, 2])
+def test_pipeline_keeps_eq1_measurement_count_with_ignore(ignore):
+    """A strategy-built pipeline spends the default CSA's Eq.1 budget,
+    max_iter * (ignore + 1) * num_opt, through the Autotuning driver."""
+    at = Autotuning(0, 63, ignore=ignore, dim=1, strategy="csa+nm", num_opt=4, max_iter=5)
+    at.entire_exec(lambda p: (p - 31) ** 2)
+    assert at.num_measurements == 5 * (ignore + 1) * 4
+
+
 def test_autotuning_single_optimizer_trajectory_pinned():
     """Regression pin: optimizer=CSA construction is bit-for-bit identical to
     the pre-strategy-layer driver (visited points and costs hard-coded)."""
@@ -420,10 +443,8 @@ def test_autotuning_single_optimizer_trajectory_pinned():
 
 def test_pipeline_not_worse_than_csa_on_shootout_models():
     """Acceptance: Pipeline([CSA, NM]) with a shared budget finds a best
-    <= pure CSA's on the deterministic strategy_shootout cost models, at the
-    same total tell count."""
-    from benchmarks.strategy_shootout import COST_MODELS
-
+    <= pure CSA's on the deterministic cost models, at the same total tell
+    count."""
     budget = 120
     for fname, fn in COST_MODELS.items():
         pipe_bests, csa_bests = [], []
